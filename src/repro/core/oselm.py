@@ -282,6 +282,9 @@ def fleet_rank1_update_h(
         den = 1.0 + jnp.einsum("sn,sn->s", hm, pht)  # (S,) = 1 + h P hᵀ
         new_p = state.P - pht[:, :, None] * (pht[:, None, :] / den[:, None, None])
         new_p = 0.5 * (new_p + new_p.transpose(0, 2, 1))  # symmetry (numerics)
+        # Symmetrizing would move a masked stream's P that is symmetric only
+        # to rounding (a batch-initialized one): keep it bit for bit.
+        new_p = jnp.where(mask[:, None, None] > 0, new_p, state.P)
         err = ym - jnp.einsum("sn,snm->sm", hm, state.beta)  # (S, m)
         # Rank-1 identity: P' hᵀ = (P - P hᵀh P/den) hᵀ = pht/den, so the
         # innovation beta' = beta + P' hᵀ e needs no (N, N) x (N, m) matmul

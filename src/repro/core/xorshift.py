@@ -22,7 +22,6 @@ import numpy as np
 
 # Paper coefficients: x ^= x << 7; x ^= x >> 9; x ^= x << 8  (mod 2^16).
 SHIFT_A, SHIFT_B, SHIFT_C = 7, 9, 8
-_MASK16 = jnp.uint16(0xFFFF)
 DEFAULT_ROUNDS = 3
 DEFAULT_SEED = 0x2D2A  # arbitrary nonzero 16-bit constant
 

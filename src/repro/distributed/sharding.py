@@ -205,15 +205,9 @@ def fleet_axis_size() -> int:
 
 
 def shard_map(f, mesh, in_specs, out_specs, check=False):
-    """``jax.shard_map`` across jax versions: >= 0.5 exposes it top-level
-    with ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check)
 
 
 def constrain_fleet(tree):

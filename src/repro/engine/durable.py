@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.engine import multiplex, snapshot, stream
 from repro.engine.types import EngineConfig, EngineState
+from repro.runtime import accel
 from repro.runtime.checkpoint import CheckpointManager
 
 
@@ -193,7 +194,13 @@ def _smoke_run(snapshot_dir: str, ticks: int, snapshot_every: int,
 def _crash_smoke(ticks: int, snapshot_every: int) -> int:
     """Phase 1: child runs slowly with cadence snapshots; parent SIGKILLs it
     once every tenant has a published snapshot.  Phase 2: resume in-process
-    from the snapshot directory, run to completion, assert reconciliation."""
+    from the snapshot directory, run to completion, assert reconciliation.
+
+    The parent stays off JAX until the child is dead: the child needs the
+    device, and on a TPU host a parent holding it would starve the child."""
+    if accel.backend_initialized():
+        raise RuntimeError("crash smoke: this process already holds a JAX "
+                           "backend, so the child could not claim the device")
     src_root = str(pathlib.Path(__file__).resolve().parents[2])
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
@@ -229,6 +236,9 @@ def _crash_smoke(ticks: int, snapshot_every: int) -> int:
             if child.poll() is None:
                 child.kill()
                 child.wait(timeout=30)
+        if accel.backend_initialized():
+            raise RuntimeError("crash smoke: the parent touched JAX while "
+                               "the child was alive")
         report = _smoke_run(d, ticks, snapshot_every, tick_sleep_s=0.0, resume=True)
         # odlint: disable=ODL005 -- CI crash-smoke CLI prints its report
         print(json.dumps(report, indent=2))
@@ -255,6 +265,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tick-sleep-ms", type=int, default=0)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    accel.enable_compile_cache()
     if args.crash_smoke:
         return _crash_smoke(args.ticks, args.snapshot_every)
     if args.smoke_child:

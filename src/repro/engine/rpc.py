@@ -1246,11 +1246,14 @@ def loopback_server(n_out: int = 6, delay_s: float = 0.0,
                     secret: Optional[str] = None, loss_prob: float = 0.0,
                     jitter_s: float = 0.0):
     """Spawn ``python -m repro.engine.rpc`` as a subprocess label server on
-    an ephemeral loopback port; yields ``(host, port)`` and tears the
-    process down on exit."""
+    an ephemeral loopback port, on the CPU backend; yields ``(host, port)``
+    and tears the process down on exit."""
     src_root = str(pathlib.Path(__file__).resolve().parents[2])
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
+    # The label server computes on the host only: it must never claim the
+    # chip the parent serves on (nor silently fall back to another device).
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.engine.rpc", "--port", "0",
            "--n-out", str(n_out), "--delay-ms", str(int(delay_s * 1000)),
            "--loss-prob", str(loss_prob),

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels, with backend dispatch.
 
-On CPU (this container) kernels run in interpret mode — the kernel body
-executes as Python/jnp, validating the exact tiling/accumulation logic the
-TPU would run.  On a real TPU backend ``interpret=False`` compiles to Mosaic.
+On the CPU backend kernels run in interpret mode — the kernel body executes
+as Python/jnp, validating the exact tiling/accumulation logic the TPU would
+run.  Every other backend compiles them (Mosaic on a TPU); a backend that
+cannot fails loudly instead of quietly interpreting.
 """
 
 from __future__ import annotations
@@ -16,7 +17,7 @@ from repro.kernels import xorshift_proj as _xorshift_proj
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def xorshift_projection(

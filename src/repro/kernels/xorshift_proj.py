@@ -58,8 +58,10 @@ def _alpha_tile(
     x = (np.uint32(seed) ^ ctr) & _M16
     x = jnp.where(x == 0, np.uint32(0x9E37), x)  # avoid the zero fixed point
     x = _mix16_u32(x, DEFAULT_ROUNDS)
-    # u16 -> [-1, 1)
-    return x.astype(jnp.float32) * np.float32(1.0 / 32768.0) - np.float32(1.0)
+    # u16 -> [-1, 1).  Mosaic has no uint32 -> float32 cast; the values are
+    # below 2^16, so the detour through int32 is exact.
+    x = x.astype(jnp.int32).astype(jnp.float32)
+    return x * np.float32(1.0 / 32768.0) - np.float32(1.0)
 
 
 def _proj_kernel(

@@ -42,15 +42,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import encdec, model as model_lib
 
 
-
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on new jax, [dict] on 0.4.x."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _rules_for(cfg, variant: str, mesh):
     """Sharding-rule overrides per arch + hillclimb variant."""
     msize = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
@@ -179,7 +170,7 @@ def _cost_compile(cfg, shape, mesh, rules, param_dtype, w16=False):
             cfg, shape, TrainConfig(microbatches=1, param_dtype=param_dtype), w16
         )
         compiled = jitted.lower(*args).compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     census = collective_census(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -250,7 +241,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, variant: str = "base",
         compiled = lowered.compile()
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     census = collective_census(compiled.as_text())
     arg_b = int(getattr(mem, "argument_size_in_bytes", 0))
     out_b = int(getattr(mem, "output_size_in_bytes", 0))

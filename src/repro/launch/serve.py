@@ -77,7 +77,7 @@ import numpy as np
 from repro import configs, engine
 from repro.engine import multiplex, rpc, snapshot, stream
 from repro.models import model as model_lib
-from repro.runtime import telemetry
+from repro.runtime import accel, telemetry
 
 
 def _decode_feats(params, state, prompts, cfg, gen_tokens):
@@ -89,6 +89,15 @@ def _decode_feats(params, state, prompts, cfg, gen_tokens):
         logits, feats, state = step(params, state, tok)
         tok = jnp.argmax(logits, -1)[:, None]
         yield feats
+
+
+def _all_finite(tree) -> bool:
+    """Every floating leaf of a (device) pytree is finite."""
+    return all(
+        bool(np.isfinite(a).all())
+        for a in map(np.asarray, jax.tree.leaves(tree))
+        if np.issubdtype(a.dtype, np.floating)
+    )
 
 
 def _print_stream_report(parsed: dict) -> dict:
@@ -299,15 +308,27 @@ def serve_fleet(workers: int = 2, tenants: int = 4, batch: int = 4,
         # instead of all packing onto the first worker.
         worker_capacity = max(1, -(-tenants // workers))
 
+    # One worker per chip, and the router itself stays off JAX: a router
+    # holding the chips would leave none for its workers.
+    chips = accel.host_tpu_chips()
+    if chips and workers > chips:
+        raise ValueError(f"--workers {workers} needs a TPU chip each; this "
+                         f"host has {chips}")
+    if chips and accel.backend_initialized():
+        raise RuntimeError("the fleet router already holds a JAX backend; "
+                           "its workers could not claim their chips")
     # Router-side telemetry: migrate.ship spans land in THIS process's
     # trace; each worker keeps its own registry, scraped over the control
     # socket (router.fleet_metrics).
     tel = telemetry.enable()
     tel.registry.clear()
-    fleet = [elastic.spawn_worker(f"w{i}") for i in range(workers)]
+    fleet = [elastic.spawn_worker(f"w{i}", chip=i) for i in range(workers)]
     router = elastic.Router(fleet, capacity=worker_capacity)
     collected: dict = {}
     try:
+        devices = {w.name: w.status()["device"] for w in fleet}
+        for name, dev in devices.items():
+            print(f"worker {name} holds {dev['name']} ({dev['kind']})")
         specs = []
         for i in range(tenants):
             spec = worker_mod.tenant_spec(
@@ -335,15 +356,19 @@ def serve_fleet(workers: int = 2, tenants: int = 4, batch: int = 4,
                 row = next((t for t in st["live"] if t["name"] == "tenant0"),
                            None)
                 if row is None:
-                    print("tenant0 finished before the migration point "
-                          "(--tokens too small); continuing without migration")
-                    break
+                    raise RuntimeError(
+                        "tenant0 finished before the migration point "
+                        "(--tokens too small): the requested migration did "
+                        "not happen")
                 if row["t"] >= target:
                     dst = router.migrate("tenant0")
                     print(f"tenant0 migrated {src.name} -> {dst.name} "
                           f"at tick >= {row['t']}")
                     break
                 time_mod.sleep(0.02)
+            else:
+                raise TimeoutError("tenant0 never reached the migration "
+                                   f"point (tick {target})")
 
         # Mid-run live scrape: every worker's registry over the control
         # socket, while tenants still stream.  The scraped identity
@@ -404,6 +429,7 @@ def serve_fleet(workers: int = 2, tenants: int = 4, batch: int = 4,
             traces["router"] = tel.tracer.chrome_trace()
             _write_metrics_json(metrics_json, {
                 "mode": "fleet", "workers_spawned": workers,
+                "devices": devices,
                 "workers": final_scrape["workers"],
                 "report": report,
                 "aggregate": {k: v for k, v in agg.items()
@@ -574,11 +600,9 @@ def serve(arch: str, variant: str = "smoke", batch: int = 4, prompt_len: int = 1
                 if mux.session("tenant0").t >= max(2, gen_tokens // 2):
                     break
             if mux.finished("tenant0"):
-                # Too few tokens for a mid-stream cut: nothing to migrate.
-                print("tenant0 finished before the migration point "
-                      "(--tokens too small); serving without migration")
-                migrate = False
-        if migrate:
+                raise RuntimeError(
+                    "tenant0 finished before the migration point (--tokens "
+                    "too small): the requested migration did not happen")
             tree, rest_ticks = mux.extract("tenant0")
             results, agg = mux.run()  # finish the remaining tenants
             fresh = make_teacher(0)
@@ -665,6 +689,11 @@ def serve(arch: str, variant: str = "smoke", batch: int = 4, prompt_len: int = 1
             "aggregate": {"stream_steps": agg.stream_steps,
                           "wall_s": agg.wall_s,
                           "steps_per_s": agg.steps_per_s},
+            "tenants": {name: {"tick_p50_ms": r.stats.tick_p50_ms,
+                               "tick_p95_ms": r.stats.tick_p95_ms,
+                               "reconciled": r.stats.reconciled,
+                               "state_finite": _all_finite(r.state)}
+                        for name, r in results.items()},
             "prometheus": tel.registry.prometheus_text(),
             "registry": tel.registry.snapshot(),
             "label_server": label_server_stats,
@@ -762,6 +791,7 @@ def main(argv=None):
                     "text + per-tenant report) to PATH, plus Chrome "
                     "trace_event files PATH.<tag>.trace.json")
     args = ap.parse_args(argv)
+    accel.enable_compile_cache()
     if args.workers:
         return serve_fleet(
             workers=args.workers, tenants=args.tenants, batch=args.batch,
@@ -778,19 +808,20 @@ def main(argv=None):
             drain=not args.no_fleet_drain,
             snapshot_full_every=args.snapshot_full_every,
             metrics_json=args.metrics_json)
-    serve(args.arch, args.variant, batch=args.batch, gen_tokens=args.tokens,
-          teacher_latency=args.teacher_latency, teacher_jitter=args.teacher_jitter,
-          teacher_loss=args.teacher_loss, pending_capacity=args.pending_capacity,
-          tenants=args.tenants, backpressure=args.backpressure,
-          teacher=args.teacher, rpc_timeout_s=args.rpc_timeout,
-          teacher_batch_window_s=args.teacher_batch_window / 1e3,
-          teacher_batch_max=args.teacher_batch_max,
-          teacher_secret=args.teacher_secret, sched=args.sched,
-          snapshot_dir=args.snapshot_dir, snapshot_every=args.snapshot_every,
-          resume=args.resume, migrate=args.migrate,
-          fuse_cohorts=args.fuse_cohorts == "on",
-          teacher_compress=args.teacher_compress,
-          mesh_fleet=args.mesh_fleet, metrics_json=args.metrics_json)
+    return serve(
+        args.arch, args.variant, batch=args.batch, gen_tokens=args.tokens,
+        teacher_latency=args.teacher_latency, teacher_jitter=args.teacher_jitter,
+        teacher_loss=args.teacher_loss, pending_capacity=args.pending_capacity,
+        tenants=args.tenants, backpressure=args.backpressure,
+        teacher=args.teacher, rpc_timeout_s=args.rpc_timeout,
+        teacher_batch_window_s=args.teacher_batch_window / 1e3,
+        teacher_batch_max=args.teacher_batch_max,
+        teacher_secret=args.teacher_secret, sched=args.sched,
+        snapshot_dir=args.snapshot_dir, snapshot_every=args.snapshot_every,
+        resume=args.resume, migrate=args.migrate,
+        fuse_cohorts=args.fuse_cohorts == "on",
+        teacher_compress=args.teacher_compress,
+        mesh_fleet=args.mesh_fleet, metrics_json=args.metrics_json)
 
 
 if __name__ == "__main__":

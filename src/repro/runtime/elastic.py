@@ -43,7 +43,7 @@ import time
 from typing import Optional
 
 from repro.engine import rpc, snapshot
-from repro.runtime import lockdebug
+from repro.runtime import accel, lockdebug
 from repro.runtime import telemetry as _telemetry
 from repro.runtime import worker as worker_mod
 
@@ -152,13 +152,26 @@ def spawn_worker(
     snapshot_every: int = 0,
     snapshot_full_every: int = 8,
     env: Optional[dict] = None,
+    chip: Optional[int] = None,
 ) -> WorkerClient:
     """Launch ``python -m repro.runtime.worker`` as a subprocess and dial
-    its control port (the worker prints ``PORT <p>`` once listening)."""
+    its control port (the worker prints ``PORT <p>`` once listening).
+
+    On a TPU host every worker owns one chip: ``chip`` picks it, and a
+    worker without a chip of its own (``chip`` missing or past the host's
+    chip count) is refused rather than left to fight another process for
+    one.  Off a TPU host ``chip`` is ignored."""
     src_root = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
     child_env = dict(env if env is not None else os.environ)
+    chips = accel.host_tpu_chips(child_env)
+    if chips:
+        if chip is None or not 0 <= chip < chips:
+            raise ValueError(
+                f"worker {name!r}: this host has {chips} TPU chip(s) and each "
+                f"worker needs one of its own; got chip={chip}")
+        child_env.update(accel.one_chip_env(chip))
     child_env["PYTHONPATH"] = src_root + (
         os.pathsep + child_env["PYTHONPATH"] if child_env.get("PYTHONPATH") else ""
     )

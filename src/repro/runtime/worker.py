@@ -57,11 +57,12 @@ import threading
 import time
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.engine import fleet as fleet_mod
 from repro.engine import multiplex, snapshot, stream
-from repro.runtime import lockdebug
+from repro.runtime import accel, lockdebug
 from repro.runtime import telemetry as _telemetry
 
 TICK_KINDS = ("synth", "decode")
@@ -70,6 +71,9 @@ TEACHER_KINDS = ("latency", "rpc")
 # Scheduler idle poll while the worker has no live tenants (waiting for the
 # router to admit some).
 _IDLE_SLEEP_S = 2e-3
+# The accept loop wakes this often to notice shutdown: a thread still parked
+# in accept() when the interpreter finalizes aborts the process.
+_ACCEPT_POLL_S = 0.2
 
 
 # ---------------------------------------------------------------------------
@@ -161,8 +165,6 @@ def _build_ticks(spec: dict, decode_cache: dict) -> snapshot.ResumableTicks:
     # source).  The backbone is deterministic, so seek(k) replays the decode
     # to tick k; params/prefill are built once per distinct backbone spec
     # and shared by every tenant (and every seek) on this worker.
-    import jax
-
     from repro import configs
     from repro.launch import serve as serve_lib
     from repro.models import model as model_lib
@@ -298,8 +300,15 @@ class Worker:
         self._decode_cache: dict = {}
         self._rpc_clients: dict = {}
         self._lock = lockdebug.make_rlock("worker.Worker._lock")
+        # Hand-off for control commands: the scheduler loop re-takes _lock
+        # the moment it lets go, so a command waiting on _lock alone can
+        # starve until every tenant has finished.  A command holds the
+        # turnstile while it waits, and the loop passes the turnstile
+        # before each round.
+        self._turnstile = lockdebug.make_lock("worker.Worker._turnstile")
         self._stop = threading.Event()
         self._sock = socket.create_server((host, port))
+        self._sock.settimeout(_ACCEPT_POLL_S)
         self.host, self.port = self._sock.getsockname()[:2]
         self._conns: list[socket.socket] = []
         self._threads: list[threading.Thread] = []
@@ -315,6 +324,8 @@ class Worker:
         (no live tenants) keeps polling — the router admits tenants at any
         time."""
         while not self._stop.is_set():
+            with self._turnstile:
+                pass  # let a waiting control command in first
             with self._lock:
                 live = self.mux.round()
             if not live:
@@ -325,6 +336,7 @@ class Worker:
         from repro.engine.rpc import _shutdown_socket
 
         _shutdown_socket(self._sock)
+        self._accept_thread.join(timeout=5)
         for conn in list(self._conns):
             _shutdown_socket(conn)
         for t in self._threads:
@@ -339,8 +351,11 @@ class Worker:
         while not self._stop.is_set():
             try:
                 conn, _ = self._sock.accept()
+            except TimeoutError:
+                continue  # re-check _stop
             except OSError:
                 return  # listener closed
+            conn.settimeout(None)  # accepted sockets inherit the poll timeout
             self._conns.append(conn)
             t = threading.Thread(target=self._serve_conn, args=(conn,), daemon=True)
             self._threads.append(t)
@@ -369,7 +384,7 @@ class Worker:
     def _handle(self, header: dict, payload: bytes) -> tuple[dict, bytes]:
         cmd = header.get("kind")
         try:
-            with self._lock:
+            with self._turnstile, self._lock:
                 if cmd == "status":
                     return self._status(), b""
                 if cmd == "metrics":
@@ -397,9 +412,13 @@ class Worker:
             return {"kind": "error", "error": f"{type(e).__name__}: {e}"}, b""
 
     def _status(self) -> dict:
+        dev = jax.devices()[0]
         return {
             "kind": "status_ok",
             "worker": self.name,
+            # Which device this worker holds (on a TPU host: its own chip).
+            "device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "name": str(dev), "count": jax.device_count()},
             "live": self.mux.load_report(),
             "finished": sorted(self.mux.finished_results()),
         }
@@ -496,6 +515,7 @@ def main(argv=None) -> int:
                     help="process-local metrics registry + span tracer "
                     "(scraped via the 'metrics' control command)")
     args = ap.parse_args(argv)
+    accel.enable_compile_cache()
     worker = Worker(
         name=args.name, host=args.host, port=args.port, quantum=args.quantum,
         sched=args.sched, fuse=args.fuse_cohorts == "on", pending=args.pending,

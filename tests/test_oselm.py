@@ -56,6 +56,25 @@ def test_masked_row_is_identity():
     assert int(st1.count) == int(st0.count)
 
 
+def test_fleet_masked_stream_keeps_batch_initialized_P_bit_for_bit():
+    """A batch-initialized P is symmetric only to rounding; the fleet
+    update's symmetrization must not touch it on a masked stream (else
+    run_fleet, which updates every stream every tick, drifts from
+    stream.run, which updates only the answered ones)."""
+    cfg = oselm.OSELMConfig(n_in=24, n_hidden=32, n_out=4, ridge=1e-2)
+    x0, y0 = _data(5, 200, 24, 4)
+    head = oselm.init_state_batch(cfg, x0, y0)
+    assert not np.array_equal(np.asarray(head.P), np.asarray(head.P).T)
+    fleet = jax.tree.map(lambda a: jnp.stack([a, a]), head)
+    x, y = _data(6, 2, 24, 4)
+    new = oselm.fleet_rank1_update_h(fleet, oselm.hidden(x, cfg), y, cfg,
+                                     mask=jnp.array([1.0, 0.0]))
+    for name in ("P", "beta", "count"):
+        np.testing.assert_array_equal(np.asarray(getattr(new, name))[1],
+                                      np.asarray(getattr(fleet, name))[1])
+    assert not np.array_equal(np.asarray(new.P[0]), np.asarray(fleet.P[0]))
+
+
 def test_partial_mask_equals_subset():
     """mask=[1,0,1] must equal updating with rows {0, 2} only."""
     cfg = oselm.OSELMConfig(n_in=10, n_hidden=8, n_out=3)

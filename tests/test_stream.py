@@ -72,6 +72,44 @@ def test_zero_latency_matches_run_fleet_bit_for_bit(mode):
     assert stats.label_latency_p95 == 0.0
 
 
+def test_zero_latency_matches_run_fleet_on_the_har_protocol():
+    """The same parity at the paper's widths (n_in 561, N 128) on the HAR
+    surrogate, with batch-initialized heads and the drift detector armed
+    as the paper loop arms it — the comparison the chip smoke repeats."""
+    import dataclasses
+
+    from repro.configs import har_odl
+    from repro.data import har
+
+    cfg = dataclasses.replace(har_odl.full(), drift=drift_mod.DriftConfig(
+        warmup=48, k_sigma=3.0, enter_hits=2, exit_calm=64))
+    data = har.generate(seed=0)
+    head = oselm.init_state_batch(cfg.elm, jnp.asarray(data.train_x),
+                                  jax.nn.one_hot(data.train_y, cfg.elm.n_out))
+    s = 4
+    ticks, labels = har.drift_tick_stream(
+        data, n_streams=s, calm=400, severities=np.linspace(1.0, 3.0, s))
+    xs = np.stack(list(ticks))
+
+    def fresh():
+        return engine.broadcast_streams(
+            engine.init_state(cfg)._replace(elm=head), s)
+
+    st_f, out_f = engine.run_fleet(fresh(), jnp.asarray(xs),
+                                   jnp.asarray(labels), cfg, mode="algo1")
+    st_s, out_s, stats = stream.run(
+        fresh(), iter(xs), cfg,
+        stream.LatencyTeacher(stream.array_labels(labels), latency=0),
+        mode="algo1")
+    assert stats.labels_applied > 0
+    for name in out_f._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out_f, name)), np.asarray(getattr(out_s, name)),
+            err_msg=f"output field {name!r} diverged")
+    for a, b in zip(jax.tree.leaves(st_f), jax.tree.leaves(st_s)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_deferred_out_of_order_labels_train_on_query_time_features():
     """Jittered latency delivers answers out of order; every answered query
     must still train (count increments) and ``trained`` marks the tick the
