@@ -180,9 +180,21 @@ class CohortSession:
         the sessions handed back to solo operation, and whether any member
         actually advanced a tick (False for the all-start first tick).
         """
-        t0 = time.perf_counter()
         tel = _telemetry.TELEMETRY
-        tok = tel.tracer.begin("cohort.tick") if tel is not None else None
+        if tel is None:
+            return self._tick(nxts, None, None)
+        labels = {"t": self.members[0].t if self.members else 0}
+        tok = tel.tracer.begin("cohort.tick")
+        try:
+            return self._tick(nxts, tel, labels)
+        finally:
+            tel.tracer.end(tok, **labels)
+
+    def _tick(self, nxts: list, tel, labels: Optional[dict]) -> tuple[list, bool]:
+        """``tick``'s body; with telemetry on (``tel``) it records the
+        tick's phases as ``tick.*`` spans and fills the tick span's
+        ``labels``."""
+        t0 = time.perf_counter()
         members = list(self.members)
         assert len(nxts) == len(members), "one next-tick entry per member"
         # Keep next-tick features on the host: one np.concatenate + ONE
@@ -190,16 +202,27 @@ class CohortSession:
         # plus a device-side concat — the old per-tick hot spot).  Members
         # hold their host array as ``_x``; ring tickets and snapshots only
         # ever read its values.
+        tok = tel.tracer.begin("tick.sync") if tel is not None else None
         x_host = [None if x is None else np.asarray(x) for x in nxts]
+        if tok is not None:
+            tel.tracer.end(tok, what="feats")
         full = self._aligned_full()
-        queried_full = np.asarray(full.queried) if full is not None else None
+        queried_full = None
+        if full is not None:
+            tok = tel.tracer.begin("tick.sync") if tel is not None else None
+            queried_full = np.asarray(full.queried)
+            if tok is not None:
+                tel.tracer.end(tok, what="mask")
         cols_full = None
         if queried_full is not None and any(
             m.collect and m.started() for m in members
         ):
             # One host sync per column for the whole cohort instead of one
             # per member (values are identical either way — pure movement).
+            tok = tel.tracer.begin("tick.sync") if tel is not None else None
             cols_full = {k: np.asarray(getattr(full, k)) for k in _COL_KEYS}
+            if tok is not None:
+                tel.tracer.end(tok, what="cols")
 
         # Per-member tick bookkeeping: collect, submit asks, claim replies.
         # Cross-member order is irrelevant (rows are independent); each
@@ -213,27 +236,40 @@ class CohortSession:
             ticking.append(i)
             lo, hi = self.bounds[i]
             p = m._p
-            queried_host = (
-                queried_full[lo:hi] if queried_full is not None
-                else np.asarray(p.queried)
-            )
+            if queried_full is not None:
+                queried_host = queried_full[lo:hi]
+            else:
+                tok = tel.tracer.begin("tick.sync") if tel is not None else None
+                queried_host = np.asarray(p.queried)
+                if tok is not None:
+                    tel.tracer.end(tok, what="mask")
             if m.collect:
-                for k in _COL_KEYS:
-                    m._cols[k].append(
-                        cols_full[k][lo:hi] if cols_full is not None
-                        else np.asarray(getattr(p, k))
-                    )
+                if cols_full is not None:
+                    for k in _COL_KEYS:
+                        m._cols[k].append(cols_full[k][lo:hi])
+                else:
+                    tok = tel.tracer.begin("tick.sync") if tel is not None else None
+                    for k in _COL_KEYS:
+                        m._cols[k].append(np.asarray(getattr(p, k)))
+                    if tok is not None:
+                        tel.tracer.end(tok, what="cols")
                 m._trained_rows.append(np.zeros(queried_host.shape, bool))
             n_q = int(queried_host.sum())
             if n_q:
                 m.stats.queries_issued += n_q
+                tok = tel.tracer.begin("tick.ask") if tel is not None else None
                 m._submit(m._x, queried_host, p, m.t)
+                if tok is not None:
+                    tel.tracer.end(tok, queries=n_q)
+            tok = tel.tracer.begin("tick.poll") if tel is not None else None
             member_applies = []
             for r in m.teacher.poll(m.t):
                 claimed = m._claim_entry(r, m.t)
                 if claimed is not None:
                     member_applies.append((claimed[0], claimed[1], r))
             m._flush_deferred(m.t)
+            if tok is not None:
+                tel.tracer.end(tok, replies=len(member_applies))
             applies.append(member_applies)
 
         planning = [i for i in range(len(members)) if nxts[i] is not None]
@@ -241,15 +277,20 @@ class CohortSession:
         p_next = None
 
         def x_next_stacked():
+            tok = tel.tracer.begin("tick.ship") if tel is not None else None
             hosts = [x_host[i] for i in planning]
-            return self.ship(
+            x = self.ship(
                 np.concatenate(hosts, axis=0) if len(hosts) > 1 else hosts[0]
             )
+            if tok is not None:
+                tel.tracer.end(tok)
+            return x
 
         # Learns in rounds: round j applies each member's j-th claimed
         # reply, preserving every member's own apply order while letting
         # replies that share a full plan combine into one dispatch.
         n_rounds = max((len(a) for a in applies), default=0)
+        learns = 0
         for j in range(n_rounds):
             groups: dict[int, list] = {}
             order: list[tuple[int, fleet.PlanOutput]] = []
@@ -277,24 +318,35 @@ class CohortSession:
                 and len(order) == 1
                 and not stragglers
             )
+            learns += len(order) + len(stragglers)
             for key, fullp in order:
                 args = self._group_args(fullp, groups[key])
                 if fuse:
+                    x_next = x_next_stacked()
+                    tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
                     (elm2, prune2, drift2, meter2), p_next = self._fused_fn(
                         self.state.elm, self.state.prune, self.state.drift,
-                        self.state.meter, *args, x_next_stacked(),
+                        self.state.meter, *args, x_next,
                     )
+                    if tok is not None:
+                        tel.tracer.end(tok, fn="learn_plan")
                     self.state = EngineState(
                         elm=elm2, prune=prune2, drift=drift2, meter=meter2
                     )
                 else:
+                    tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
                     new_elm, new_prune = self._learn_fn(
                         self.state.elm, self.state.prune, self.state.drift,
                         self.state.meter, *args,
                     )
+                    if tok is not None:
+                        tel.tracer.end(tok, fn="learn")
                     self.state = self.state._replace(elm=new_elm, prune=new_prune)
             for i, ent, mask, reply in stragglers:
+                tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
                 self._patch_learn(i, ent, mask, reply)
+                if tok is not None:
+                    tel.tracer.end(tok, fn="patch")
 
         # Tick accounting for members that advanced (solo `advance` parity;
         # the shared wall time lands in every advanced member's tick_ms).
@@ -326,10 +378,14 @@ class CohortSession:
 
         # Plan the next tick for everyone remaining (starts fresh members).
         if planning and p_next is None:
+            x_next = x_next_stacked()
+            tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
             (prune2, drift2, meter2), p_next = self._plan_fn(
                 self.state.elm, self.state.prune, self.state.drift,
-                self.state.meter, x_next_stacked(),
+                self.state.meter, x_next,
             )
+            if tok is not None:
+                tel.tracer.end(tok, fn="plan")
             self.state = self.state._replace(
                 prune=prune2, drift=drift2, meter=meter2
             )
@@ -352,10 +408,9 @@ class CohortSession:
                     else st.tick_rate_ema
                     + stream.TICK_RATE_EMA_ALPHA * (rate - st.tick_rate_ema)
                 )
-        if tok is not None:
-            tel.tracer.end(
-                tok, members=len(members), s=self.total, detached=len(detached)
-            )
+        if labels is not None:
+            labels.update(members=len(members), s=self.total,
+                          detached=len(detached), learns=learns)
         return detached, bool(ticking)
 
     # -- internals ---------------------------------------------------------

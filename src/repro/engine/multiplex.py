@@ -331,7 +331,7 @@ class _Slot:
         if not self.draining:
             for _ in range(n_ticks):
                 if not sess.started():
-                    x0 = next(self.it, None)
+                    x0 = self.pull()
                     if x0 is None:  # empty tick source: nothing to run
                         self.draining = True
                         break
@@ -342,7 +342,7 @@ class _Slot:
                     # stream ended: nothing left to plan, only the drain.
                     self.draining = True
                     break
-                nxt = next(self.it, None)
+                nxt = self.pull()
                 sess.advance(nxt)
                 self.last_ticks += 1
                 if nxt is None:
@@ -373,6 +373,18 @@ class _Slot:
         if it is None:
             it = self._it = iter(self.tenant.ticks)
         return it
+
+    def pull(self):
+        """This tenant's next tick from its source (None when exhausted),
+        recorded as a ``mux.source`` span when telemetry is on."""
+        tel = _telemetry.TELEMETRY
+        if tel is None:
+            return next(self.it, None)
+        tok = tel.tracer.begin("mux.source")
+        try:
+            return next(self.it, None)
+        finally:
+            tel.tracer.end(tok, tenant=self.tenant.name)
 
     def maybe_snapshot(self, force: bool = False) -> bool:
         """Cadence snapshot: capture now, write on the manager's background
@@ -455,7 +467,7 @@ class _CohortUnit:
         for _ in range(n_ticks):
             if len(self.slots) < 2:
                 break
-            nxts = [next(s.it, None) for s in self.slots]
+            nxts = [s.pull() for s in self.slots]
             detached, advanced = self.cohort.tick(nxts)
             if advanced:
                 self.last_ticks += 1
@@ -801,6 +813,20 @@ class Multiplexer:
             self._t0 = time.perf_counter()
         if not self._live:
             return False
+        tel = _telemetry.TELEMETRY
+        if tel is None:
+            return self._schedule(None, None)
+        labels: dict = {}
+        tok = tel.tracer.begin("mux.round")
+        try:
+            return self._schedule(tel, labels)
+        finally:
+            tel.tracer.end(tok, **labels)
+
+    def _schedule(self, tel, labels: Optional[dict]) -> bool:
+        """One round's scheduling (``_round``'s body); with telemetry on
+        (``tel``) it meters the round and fills the ``mux.round`` span's
+        ``labels``."""
         self.agg.rounds += 1
         if self.fuse:
             self._form_cohorts()
@@ -830,7 +856,6 @@ class Multiplexer:
             for u in self._live:
                 nxt.extend(self._step_unit(u, self.quantum))
             self._live = nxt
-        tel = _telemetry.TELEMETRY
         if tel is not None:
             # Scheduler-level meters: rounds, and the stream-step debits
             # this round actually charged (ticks × per-unit cost S — the
@@ -842,6 +867,8 @@ class Multiplexer:
                 sum(u.last_ticks * u.s for u in units),
             )
             tel.registry.gauge("odl_mux_live_units", len(self._live))
+            labels.update(units=len(units),
+                          ticks=sum(u.last_ticks for u in units))
         return bool(self._live)
 
     def run(self) -> tuple[dict[str, TenantResult], MultiplexStats]:

@@ -710,18 +710,40 @@ class StreamSession:
         iterator is exhausted — the learn of any same-tick replies then runs
         unfused.  Mirrors one iteration of the double-buffered ``run`` loop.
         """
+        tel = _telemetry.TELEMETRY
+        if tel is None:
+            self._advance(nxt, None, None)
+            return
+        labels = {"t": self.t, **self.telemetry_labels}
+        tok = tel.tracer.begin("stream.tick")
+        try:
+            self._advance(nxt, tel, labels)
+        finally:
+            tel.tracer.end(tok, **labels)
+
+    def _advance(self, nxt, tel, labels: Optional[dict]) -> None:
+        """``advance``'s body; with telemetry on (``tel``) it records the
+        tick's phases as ``tick.*`` spans and fills the tick span's
+        ``labels``."""
         x, p = self._x, self._p
         assert p is not None, "advance() before start()"
         t = self.t
         t0 = time.perf_counter()
-        tel = _telemetry.TELEMETRY
-        tok = tel.tracer.begin("stream.tick") if tel is not None else None
         if nxt is not None:
+            tok = tel.tracer.begin("tick.ship") if tel is not None else None
             nxt = self.ship(nxt)
+            if tok is not None:
+                tel.tracer.end(tok)
+        tok = tel.tracer.begin("tick.sync") if tel is not None else None
         queried_host = np.asarray(p.queried)  # host syncs on tick t here
+        if tok is not None:
+            tel.tracer.end(tok, what="mask")
         if self.collect:
+            tok = tel.tracer.begin("tick.sync") if tel is not None else None
             for k in self._cols:
                 self._cols[k].append(np.asarray(getattr(p, k)))
+            if tok is not None:
+                tel.tracer.end(tok, what="cols")
             self._trained_rows.append(np.zeros(queried_host.shape, bool))
         n_q = int(queried_host.sum())
         if n_q:
@@ -729,7 +751,11 @@ class StreamSession:
             # queries inside plan; every one of them must end in exactly one
             # of applied / dropped / lost / coalesced.
             self.stats.queries_issued += n_q
+            tok = tel.tracer.begin("tick.ask") if tel is not None else None
             self._submit(x, queried_host, p, t)
+            if tok is not None:
+                tel.tracer.end(tok, queries=n_q)
+        tok = tel.tracer.begin("tick.poll") if tel is not None else None
         applies = [
             a
             for a in (self._claim(r, t) for r in self.teacher.poll(t))
@@ -737,31 +763,39 @@ class StreamSession:
         ]
         # Replies just freed ring slots: submit deferred (``block``) asks.
         self._flush_deferred(t)
+        if tok is not None:
+            tel.tracer.end(tok, replies=len(applies))
         if nxt is not None:
             # Steady state: fuse the last reply's learn with the next tick's
             # plan into one dispatch (earlier replies, if any, apply first,
             # so all of tick t's answers land before tick t+1 is planned).
             if applies:
                 for args in applies[:-1]:
-                    self._learn(args)
+                    self._learn(args, tel)
+                tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
                 (elm2, prune2, drift2, meter2), p_next = self._fused_fn(
                     self.state.elm, self.state.prune, self.state.drift,
                     self.state.meter, *applies[-1], nxt,
                 )
+                if tok is not None:
+                    tel.tracer.end(tok, fn="learn_plan")
                 self.state = EngineState(
                     elm=elm2, prune=prune2, drift=drift2, meter=meter2
                 )
             else:
+                tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
                 (new_prune, new_drift, new_meter), p_next = self._plan_fn(
                     self.state.elm, self.state.prune, self.state.drift,
                     self.state.meter, nxt
                 )
+                if tok is not None:
+                    tel.tracer.end(tok, fn="plan")
                 self.state = self.state._replace(
                     prune=new_prune, drift=new_drift, meter=new_meter
                 )
         else:
             for args in applies:
-                self._learn(args)
+                self._learn(args, tel)
             p_next = None
         self.stats.ticks += 1
         self.stats.stream_steps += (
@@ -777,8 +811,8 @@ class StreamSession:
             )
         self.t += 1
         self._x, self._p = nxt, p_next
-        if tok is not None:
-            tel.tracer.end(tok, t=t, queries=n_q, **self.telemetry_labels)
+        if labels is not None:
+            labels.update(queries=n_q, learns=len(applies))
 
     def drain_replies(
         self,
@@ -1081,11 +1115,16 @@ class StreamSession:
         ent, mask = claimed
         return self._build_learn_args(ent, reply, mask)
 
-    def _learn(self, args) -> None:
+    def _learn(self, args, tel=None) -> None:
+        """One unfused learn dispatch; inside a tick its caller passes the
+        enabled telemetry (``tel``) to record it as ``tick.dispatch``."""
+        tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
         new_elm, new_prune = self._learn_fn(
             self.state.elm, self.state.prune, self.state.drift, self.state.meter,
             *args
         )
+        if tok is not None:
+            tel.tracer.end(tok, fn="learn")
         self.state = self.state._replace(elm=new_elm, prune=new_prune)
 
 

@@ -9,11 +9,21 @@ This module gives them one shared, scrape-able surface:
 * a metrics **registry** — named counters / gauges / histograms with
   label sets (``{tenant, worker, shard, cohort}``), exported as
   Prometheus text exposition or a JSON snapshot; and
-* a bounded ring-buffer **span tracer** — monotonic-clock spans for tick
-  plan/learn, teacher ask→reply, ring evictions, snapshot save/restore,
-  RPC flush/reconnect, cohort pack/dissolve, and migration
-  extract→ship→admit — exported as Chrome ``trace_event`` JSON (open it
-  in ``chrome://tracing`` / Perfetto) or a JSONL event log.
+* a bounded ring-buffer **span tracer** — monotonic-clock spans for
+  scheduler rounds, tick-source pulls, whole ticks and the phases inside
+  them (host syncs, teacher ask and poll, next-tick ship, device
+  dispatch), ring evictions, snapshot save/restore, RPC flush/reconnect,
+  cohort pack/dissolve, and migration extract→ship→admit — exported as
+  Chrome ``trace_event`` JSON (open it in ``chrome://tracing`` /
+  Perfetto).  ``SPAN_NAMES`` catalogs every name the program records.
+
+Profiler mode (``enable(profiler=True)``): every recorded span also
+enters a ``jax.profiler.TraceAnnotation`` at ``begin`` and exits it at
+``end``, with its labels as the event's metadata, so a running
+``jax.profiler`` trace holds the program's spans on the host plane, on
+the device trace's clock and nested as they ran on each thread.  The ring
+records exactly as without it.  JAX is imported only then: the router and
+the crash-smoke parent import this module and stay off JAX.
 
 Design constraints (these are load-bearing — the streaming hot path is
 instrumented per tick and ``benchmarks/stream_bench.py`` gates the
@@ -48,7 +58,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import json
 import os
 import threading
 import time
@@ -57,11 +66,28 @@ from typing import Optional
 from repro.runtime import lockdebug
 
 __all__ = [
-    "Registry", "SpanTracer", "Telemetry", "TELEMETRY",
+    "Registry", "SpanTracer", "Telemetry", "TELEMETRY", "SPAN_NAMES",
     "enable", "disable", "get",
     "sync_stream_stats", "parse_prometheus", "check_stream_identity",
     "STREAM_COUNTER_FIELDS", "STREAM_GAUGE_FIELDS", "STREAM_MIRROR_EXCLUDED",
 ]
+
+
+# Every span and event name the program records (tests/test_telemetry.py
+# keeps this and the literal names under src/ equal): what a reader of a
+# profiler trace keys on to pick the program's events out of the host plane.
+SPAN_NAMES = (
+    # scheduler (engine/multiplex.py)
+    "mux.round", "mux.source",
+    # one tick of a solo session or a fused cohort, and its phases
+    "stream.tick", "cohort.tick",
+    "tick.sync", "tick.ask", "tick.poll", "tick.ship", "tick.dispatch",
+    # events and spans off the tick path
+    "ring.evict", "cohort.pack", "cohort.dissolve",
+    "rpc.flush", "rpc.reconnect",
+    "snapshot.save", "snapshot.restore",
+    "migrate.extract", "migrate.ship", "migrate.admit",
+)
 
 
 def _label_key(labels: dict) -> tuple:
@@ -202,16 +228,24 @@ class SpanTracer:
     (zero-duration, never sampled — evictions, reconnects, drops are rare
     and individually meaningful).  The ring is ``deque(maxlen=capacity)``:
     old spans fall off, memory is bounded, nothing is ever flushed on the
-    hot path.
+    hot path.  With ``profiler`` each recorded span is also a
+    ``jax.profiler.TraceAnnotation``, entered by ``begin`` and exited by
+    ``end`` on the same thread, last in first out.
     """
 
-    def __init__(self, capacity: int = 8192, sample: int = 1):
+    def __init__(self, capacity: int = 8192, sample: int = 1,
+                 profiler: bool = False):
         self.capacity = int(capacity)
         self.sample = max(1, int(sample))
         self._ring: "collections.deque" = collections.deque(maxlen=self.capacity)
         self._lock = lockdebug.make_lock("telemetry.SpanTracer._lock")
         self._seq: "dict[str, int]" = {}
         self.dropped = 0  # sampled-out spans (visibility into what's missing)
+        self._annotation = None
+        if profiler:
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation
 
     def begin(self, name: str):
         if self.sample > 1:
@@ -223,13 +257,22 @@ class SpanTracer:
                     # the lock loses updates under contention.
                     self.dropped += 1  # odlint: guarded-by(_lock)
                     return None
-        return (name, time.monotonic_ns())
+        if self._annotation is None:
+            return (name, time.monotonic_ns())
+        ann = self._annotation(name)
+        ann.__enter__()
+        return (name, time.monotonic_ns(), ann)
 
     def end(self, token, **labels) -> None:
         if token is None:
             return
-        name, t0 = token
         now = time.monotonic_ns()
+        name, t0 = token[0], token[1]
+        if len(token) == 3:  # profiler mode: close the annotation
+            ann = token[2]
+            if labels:
+                ann.set_metadata(**labels)
+            ann.__exit__(None, None, None)
         self._ring.append(
             (name, t0, now - t0, threading.get_ident(), labels or None)
         )
@@ -278,16 +321,6 @@ class SpanTracer:
             events.append(ev)
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
-    def to_jsonl(self) -> str:
-        """One JSON object per line — greppable event log."""
-        lines = []
-        for name, t0, dur, tid, labels in self.spans():
-            row = {"name": name, "ts_ns": t0, "dur_ns": dur, "tid": tid}
-            if labels:
-                row.update(labels)
-            lines.append(json.dumps(row, default=str))
-        return "\n".join(lines) + ("\n" if lines else "")
-
     def clear(self) -> None:
         self._ring.clear()
         with self._lock:
@@ -298,9 +331,11 @@ class SpanTracer:
 class Telemetry:
     """The enabled-state bundle: one registry + one tracer."""
 
-    def __init__(self, span_capacity: int = 8192, span_sample: int = 1):
+    def __init__(self, span_capacity: int = 8192, span_sample: int = 1,
+                 profiler: bool = False):
         self.registry = Registry()
-        self.tracer = SpanTracer(capacity=span_capacity, sample=span_sample)
+        self.tracer = SpanTracer(capacity=span_capacity, sample=span_sample,
+                                 profiler=profiler)
 
 
 # The global switch.  ``None`` == disabled: instrumentation sites read
@@ -308,12 +343,20 @@ class Telemetry:
 TELEMETRY: Optional[Telemetry] = None
 
 
-def enable(span_capacity: int = 8192, span_sample: int = 1) -> Telemetry:
+def enable(span_capacity: int = 8192, span_sample: int = 1,
+           profiler: bool = False) -> Telemetry:
     """Turn telemetry on process-wide (idempotent: an existing enabled
-    instance is kept so counters survive repeated calls)."""
+    instance is kept so counters survive repeated calls).  ``profiler``
+    also puts every recorded span on a running ``jax.profiler`` trace (see
+    the module docstring); asking for it of an instance enabled without it
+    is an error, so no caller silently misses its spans."""
     global TELEMETRY
     if TELEMETRY is None:
-        TELEMETRY = Telemetry(span_capacity=span_capacity, span_sample=span_sample)
+        TELEMETRY = Telemetry(span_capacity=span_capacity, span_sample=span_sample,
+                              profiler=profiler)
+    elif profiler and TELEMETRY.tracer._annotation is None:
+        raise RuntimeError("telemetry is enabled without profiler mode; "
+                           "disable() it first")
     return TELEMETRY
 
 

@@ -1,10 +1,19 @@
 """Fleet telemetry tests (ISSUE 9): registry/tracer/exporter basics, the
 StreamStats<->registry cross-check lock for every backpressure policy,
 bit-for-bit parity of instrumented vs uninstrumented runs, snapshot
-restore semantics for the load-signal gauges vs the trace ring, and the
-LabelServer wire ``stats`` scrape."""
+restore semantics for the load-signal gauges vs the trace ring, the
+LabelServer wire ``stats`` scrape, and profiler mode: the program's spans
+on a ``jax.profiler`` trace, nested as they ran, with ``SPAN_NAMES`` the
+catalog of every name the program records."""
 
+import ast
+import collections
 import dataclasses
+import glob
+import os
+import pathlib
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +23,11 @@ import pytest
 from repro import engine
 from repro.core import drift as drift_mod
 from repro.core import oselm, pruning
-from repro.engine import rpc, snapshot, stream
+from repro.engine import multiplex, rpc, snapshot, stream
 from repro.runtime import telemetry
+
+SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
+TICK_SPANS = ("stream.tick", "cohort.tick")
 
 
 @pytest.fixture(autouse=True)
@@ -110,8 +122,6 @@ def test_tracer_spans_events_sampling_and_bounded_ring():
     phases = {ev["name"]: ev["ph"] for ev in trace["traceEvents"]}
     assert phases["rpc.reconnect"] == "i"  # instant
     assert phases.get("stream.tick", "X") == "X"  # complete span
-    jsonl = tr.to_jsonl()
-    assert "rpc.reconnect" in jsonl and jsonl.endswith("\n")
 
     tr.clear()
     assert tr.spans() == [] and tr.dropped == 0
@@ -376,3 +386,135 @@ def test_rpc_client_mirrors_wire_meters_into_registry():
         client.close()
     finally:
         server.close()
+
+
+# ---------------------------------------------------------------------------
+# Profiler mode: the program's spans on the profiler's host plane.
+# ---------------------------------------------------------------------------
+
+
+def _mux_run():
+    """Two same-width tenants fused into one cohort and a third of another
+    width ticking solo, through the multiplexer; a teacher with jitter, so
+    some ticks learn twice."""
+    cfg = _cfg(min_trained=1)
+    tenants = []
+    for i, s_len in enumerate((4, 4, 3)):
+        xs, ys = _stream_data(cfg, 9, s_len, seed=20 + i)
+        teacher = stream.LatencyTeacher(stream.array_labels(ys), latency=1,
+                                        jitter=2, seed=i)
+        tenants.append(multiplex.Tenant(
+            f"t{i}", engine.init_fleet(cfg, s_len), [xs[t] for t in range(9)], cfg,
+            teacher, mode="train_phase", capacity=4, backpressure="drop_oldest"))
+    return multiplex.run(tenants, quantum=3, fuse=True)[0]
+
+
+def _profiler_spans(trace_dir):
+    """``(name, start, end, line, stats)`` of every host event of the trace
+    whose name the program records; ``line`` tells the host's threads
+    apart."""
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         (plane.name, i), dict(e.stats))
+                        for e in line.events if e.name in telemetry.SPAN_NAMES]
+    return out
+
+
+def _encloses(outer, inner):
+    return outer[3] == inner[3] and outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_profiler_mode_puts_every_span_on_the_trace_nested_in_its_tick(tmp_path):
+    tel = telemetry.enable(profiler=True)
+    with jax.profiler.trace(str(tmp_path)):
+        _mux_run()
+    spans = _profiler_spans(str(tmp_path))
+    # The ring and the profiler hold the same spans (events are instants,
+    # recorded in the ring only).
+    ring = collections.Counter(s[0] for s in tel.tracer.spans() if s[2] > 0)
+    assert collections.Counter(s[0] for s in spans) == ring
+    assert {"mux.round", "mux.source", *TICK_SPANS, "tick.sync", "tick.ask",
+            "tick.poll", "tick.ship", "tick.dispatch"} <= set(ring)
+
+    ticks = [s for s in spans if s[0] in TICK_SPANS]
+    rounds = [s for s in spans if s[0] == "mux.round"]
+    for sp in spans:
+        if sp[0].startswith("tick."):
+            assert any(_encloses(t, sp) for t in ticks), sp
+        if sp[0] in TICK_SPANS or sp[0] == "mux.source":
+            assert any(_encloses(r, sp) for r in rounds), sp
+    for t in ticks:
+        # Every tick reads its query mask back from the device.
+        assert any(c[0] == "tick.sync" and _encloses(t, c) for c in spans), t
+        assert "learns" in t[4] and "t" in t[4]
+    assert max(t[4]["learns"] for t in ticks) >= 2
+    # A fused cohort syncs on the next tick's features, a solo tick does not.
+    syncs = collections.Counter(
+        (t[0], c[4]["what"]) for t in ticks for c in spans
+        if c[0] == "tick.sync" and _encloses(t, c))
+    assert syncs[("cohort.tick", "feats")] and not syncs[("stream.tick", "feats")]
+    assert syncs[("stream.tick", "mask")] and syncs[("stream.tick", "cols")]
+
+
+def test_telemetry_off_builds_no_annotation_and_leaves_the_run_bitwise(
+        monkeypatch, tmp_path):
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    plain = _mux_run()
+    assert built == []
+
+    tel = telemetry.enable(profiler=True)
+    with jax.profiler.trace(str(tmp_path)):
+        traced = _mux_run()
+    assert len(built) == sum(1 for s in tel.tracer.spans() if s[2] > 0) > 0
+    for name, r in plain.items():
+        for (path, a), (_, b) in zip(
+            jax.tree_util.tree_flatten_with_path(r.state)[0],
+            jax.tree_util.tree_flatten_with_path(traced[name].state)[0],
+        ):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"{name} leaf {path}")
+        np.testing.assert_array_equal(r.outputs.pred, traced[name].outputs.pred)
+        for f in telemetry.STREAM_COUNTER_FIELDS:
+            assert getattr(r.stats, f) == getattr(traced[name].stats, f), f
+
+
+def test_span_names_catalog_every_name_the_program_records():
+    used = set()
+    for path in SRC.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in ("begin", "span", "event") and node.args
+                    and isinstance(node.args[0], ast.Constant)
+                    and isinstance(node.args[0].value, str)):
+                used.add(node.args[0].value)
+    assert used == set(telemetry.SPAN_NAMES)
+    assert len(telemetry.SPAN_NAMES) == len(set(telemetry.SPAN_NAMES))
+
+
+def test_profiler_mode_needs_a_fresh_instance_and_plain_mode_stays_off_jax():
+    telemetry.enable()
+    with pytest.raises(RuntimeError, match="profiler mode"):
+        telemetry.enable(profiler=True)
+    # The router and the crash-smoke parent import telemetry and record
+    # spans without loading JAX.
+    code = ("import sys\n"
+            "from repro.runtime import telemetry\n"
+            "tel = telemetry.enable()\n"
+            "tel.tracer.end(tel.tracer.begin('migrate.ship'), tenant='a')\n"
+            "assert tel.tracer.chrome_trace()['traceEvents']\n"
+            "print(sorted(m for m in sys.modules if m.split('.')[0] in ('jax', 'jaxlib')))\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=dict(os.environ, PYTHONPATH=str(SRC)), timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
