@@ -50,7 +50,9 @@ from an *iterator* of (S, n_in) ticks — nothing materializes over T.  A
 latency; ``stream.LatencyTeacher`` models latency, jitter, loss, and
 permanent outage.  In-flight tickets wait in a fixed-capacity
 ``PendingRing`` (overflow drops the oldest, metered), answers apply out of
-order through masked ``learn``, and host ingestion of tick t+1 overlaps
+order through masked ``learn`` — over the answered rows alone when they
+fit a compact row capacity (``fleet.learn_rows``, ``stream.learn_rows_index``),
+so P traffic follows the query rate — and host ingestion of tick t+1 overlaps
 device compute of tick t (double buffering).  ``StreamStats`` reports
 p50/p95 tick latency, label latency in ticks, and drop/orphan/loss
 counters.  With a zero-latency teacher the runtime reproduces

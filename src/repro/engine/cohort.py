@@ -29,9 +29,10 @@ Replies demultiplex back through three learn paths, chosen per reply:
 * **aligned** — the common case: a reply whose ring entry is a
   ``stream.PlanSlice`` of a full-width plan at the member's current
   bounds.  All aligned replies of a round that share the same full plan
-  combine into ONE full-width learn: each member's mask/labels scatter
-  into their row window and everyone else's rows ride along under
-  ``mask=False``, which is an exact identity.
+  combine into ONE learn: each member's mask/labels scatter into their
+  row window and everyone else's rows ride along under ``mask=False``,
+  which is an exact identity — or, where few rows answered, the learn
+  runs over the answered rows alone (``stream.learn_rows_index``).
 * **fused** — when the last round is a single aligned group and no member
   is joining or leaving, its learn fuses with the next tick's stacked plan
   into one dispatch (bitwise identical to the separate dispatches — the
@@ -90,6 +91,7 @@ class CohortSession:
         self._learn_fn = stream._learn_runner(self.cfg, self.donate)
         self._fused_fn = stream._learn_plan_runner(self.cfg, self.mode, self.donate)
         self._full_mask_dev = None  # cached device-side all-True apply mask
+        self._laddered: set = set()  # (runner, width) compiled by learn_call
         # Stack every founding member in ONE tree concat (attach-at-a-time
         # would pay N-1 intermediate full copies — measurable at N=16).
         for m in members:
@@ -320,27 +322,32 @@ class CohortSession:
             )
             learns += len(order) + len(stragglers)
             for key, fullp in order:
-                args = self._group_args(fullp, groups[key])
+                args, rows = self._group_args(fullp, groups[key])
+                n_rows = self.total if rows is None else len(rows)
                 if fuse:
                     x_next = x_next_stacked()
                     tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-                    (elm2, prune2, drift2, meter2), p_next = self._fused_fn(
-                        self.state.elm, self.state.prune, self.state.drift,
-                        self.state.meter, *args, x_next,
+                    (elm2, prune2, drift2, meter2), p_next = self._learn_call(
+                        self._fused_fn,
+                        (self.state.elm, self.state.prune, self.state.drift,
+                         self.state.meter, *args, x_next),
+                        rows,
                     )
                     if tok is not None:
-                        tel.tracer.end(tok, fn="learn_plan")
+                        tel.tracer.end(tok, fn="learn_plan", rows=n_rows)
                     self.state = EngineState(
                         elm=elm2, prune=prune2, drift=drift2, meter=meter2
                     )
                 else:
                     tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-                    new_elm, new_prune = self._learn_fn(
-                        self.state.elm, self.state.prune, self.state.drift,
-                        self.state.meter, *args,
+                    new_elm, new_prune = self._learn_call(
+                        self._learn_fn,
+                        (self.state.elm, self.state.prune, self.state.drift,
+                         self.state.meter, *args),
+                        rows,
                     )
                     if tok is not None:
-                        tel.tracer.end(tok, fn="learn")
+                        tel.tracer.end(tok, fn="learn", rows=n_rows)
                     self.state = self.state._replace(elm=new_elm, prune=new_prune)
             for i, ent, mask, reply in stragglers:
                 tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
@@ -438,8 +445,12 @@ class CohortSession:
 
     def _group_args(self, fullp: fleet.PlanOutput, group: list):
         """Scatter one round's aligned member masks/labels into full-width
-        learn args against their shared full plan.  Members outside the
-        group ride along under mask=False — an exact identity."""
+        learn args against their shared full plan; returns ``(args,
+        rows)``, ``rows`` the learn's ``stream.learn_rows_index``.  Members
+        outside the group ride along under mask=False — an exact identity —
+        or, when the learn compacts, are left out of it.  Each member's
+        stats meter the rows of its own window that the learn reads and
+        writes."""
         total = self.total
         mask_full = np.zeros((total,), bool)
         labels_full = np.zeros((total,), np.int32)
@@ -447,6 +458,14 @@ class CohortSession:
             lo, hi = self.bounds[i]
             mask_full[lo:hi] = mask
             labels_full[lo:hi] = np.asarray(reply.labels, np.int32)
+        rows = stream.learn_rows_index(mask_full)
+        for i, ent, mask, reply in group:
+            lo, hi = self.bounds[i]
+            self.members[i].stats.count_learn(
+                hi - lo if rows is None
+                else int(np.searchsorted(rows, hi) - np.searchsorted(rows, lo)),
+                rows is not None,
+            )
         if mask_full.all():
             if self._full_mask_dev is None or self._full_mask_dev.shape[0] != total:
                 self._full_mask_dev = jnp.ones((total,), jnp.bool_)
@@ -461,13 +480,17 @@ class CohortSession:
             mask_dev,
             fullp.controller_on,
             fullp.theta,
-        )
+        ), rows
+
+    def _learn_call(self, fn, args: tuple, rows):
+        return stream.learn_call(fn, args, rows, self.total, self._laddered, self.ship)
 
     def _patch_learn(self, i: int, ent, mask: np.ndarray, reply) -> None:
         """Straggler reply: learn one member's solo-width plan context into
         its row window of the stacked state."""
         m = self.members[i]
         lo, hi = self.bounds[i]
+        m.stats.count_learn(hi - lo, False)
         args = m._build_learn_args(ent, reply, mask)
         fn = fleet._patch_learn_runner(self.cfg, lo, hi, self.donate)
         new_elm, new_prune = fn(

@@ -286,6 +286,55 @@ def learn(
     )
 
 
+def learn_rows(
+    state: EngineState,
+    rows: Optional[jnp.ndarray],  # (K,) int32 sorted unique row index, or None
+    h: jnp.ndarray,
+    labels: jnp.ndarray,
+    pred: jnp.ndarray,
+    confidence: jnp.ndarray,
+    mask: jnp.ndarray,
+    controller_on: jnp.ndarray,
+    cfg: EngineConfig,
+    theta: Optional[jnp.ndarray] = None,
+) -> EngineState:
+    """``learn`` over the rows ``rows`` alone (``learn`` itself when
+    ``rows`` is None).
+
+    Gathers the K rows of every ``elm`` and ``prune`` leaf and of every
+    per-row argument, runs ``learn`` on that K-row state, and scatters the
+    updated rows back.  ``rows`` must be sorted, unique and in range, and
+    hold every row of ``mask`` (``stream.learn_rows_index`` builds it); a
+    row of ``rows`` outside ``mask`` learns as the identity ``learn`` makes
+    it, so it is written back as it was.  Row r of the result is therefore
+    row r of ``learn``'s, while a donated caller reads and writes K rows of
+    P instead of S.
+    """
+    if rows is None:
+        return learn(
+            state, h, labels, pred, confidence, mask, controller_on, cfg, theta=theta
+        )
+    at = dict(indices_are_sorted=True, unique_indices=True, mode="promise_in_bounds")
+
+    def take(a):
+        return a.at[rows].get(**at)
+
+    def put(full, part):
+        return full.at[rows].set(part, **at)
+
+    sub = state._replace(
+        elm=jax.tree.map(take, state.elm), prune=jax.tree.map(take, state.prune)
+    )
+    new = learn(
+        sub, take(h), take(labels), take(pred), take(confidence), take(mask),
+        take(controller_on), cfg, theta=None if theta is None else take(theta),
+    )
+    return state._replace(
+        elm=jax.tree.map(put, state.elm, new.elm),
+        prune=jax.tree.map(put, state.prune, new.prune),
+    )
+
+
 def fleet_step(
     state: EngineState,
     x: jnp.ndarray,  # (S, n_in)

@@ -385,6 +385,13 @@ class StreamStats:
     queries_coalesced: int = 0  # stream-queries settled by an in-flight ticket
     asks_deferred: int = 0  # ``block``: asks that waited for a ring slot
     tickets_reasked: int = 0  # in-flight tickets re-submitted after a restore
+    # Learn dispatch (``learn_rows_index``): replies learned over a
+    # compacted row set or at full width, and the rows of this session
+    # those learns read and wrote, padding included — so ``learn_rows /
+    # labels_applied`` is the padding overhead.
+    learns_compact: int = 0
+    learns_full: int = 0
+    learn_rows: int = 0
     wall_s: float = 0.0
     # Load signals for the elastic router (runtime/elastic.py): a wall-clock
     # EMA of the tick rate (ticks/s — NOT deterministic, excluded from
@@ -435,6 +442,15 @@ class StreamStats:
             + self.queries_coalesced
         )
 
+    def count_learn(self, rows: int, compact: bool) -> None:
+        """Meter one reply's learn: ``rows`` of this session's rows read and
+        written, over a compacted row set or at full width."""
+        if compact:
+            self.learns_compact += 1
+        else:
+            self.learns_full += 1
+        self.learn_rows += rows
+
     def summary(self) -> dict:
         return {
             "ticks": self.ticks,
@@ -452,6 +468,9 @@ class StreamStats:
             "queries_coalesced": self.queries_coalesced,
             "asks_deferred": self.asks_deferred,
             "tickets_reasked": self.tickets_reasked,
+            "learns_compact": self.learns_compact,
+            "learns_full": self.learns_full,
+            "learn_rows": self.learn_rows,
             "queries_reconciled": self.reconciled,
             "tick_rate_ema": self.tick_rate_ema,
             "ring_occupancy_hwm": self.ring_occupancy_hwm,
@@ -486,13 +505,19 @@ def _plan_runner(cfg: EngineConfig, mode: str, donate: bool):
     return jax.jit(run_plan, donate_argnums=(1, 2, 3) if donate else ())
 
 
+# The learn runners take one more argument, ``rows``: a reply's
+# ``learn_rows_index`` (``fleet.learn_rows`` then reads and writes those K
+# rows of P and beta in place of all S), or nothing, which is the
+# full-width executable as it always was.
+
+
 @functools.lru_cache(maxsize=fleet.RUNNER_CACHE_SIZE)
 def _learn_runner(cfg: EngineConfig, donate: bool):
     def run_learn(elm, prune, drift, meter, h, labels, pred, conf, mask, controller_on,
-                  theta):
+                  theta, rows=None):
         state = EngineState(elm=elm, prune=prune, drift=drift, meter=meter)
-        new_state = fleet.learn(
-            state, h, labels, pred, conf, mask, controller_on, cfg, theta=theta
+        new_state = fleet.learn_rows(
+            state, rows, h, labels, pred, conf, mask, controller_on, cfg, theta=theta
         )
         return new_state.elm, new_state.prune
 
@@ -509,11 +534,11 @@ def _learn_plan_runner(cfg: EngineConfig, mode: str, donate: bool):
 
     def run_learn_plan(
         elm, prune, drift, meter, h, labels, pred, conf, mask, controller_on, theta,
-        x_next
+        x_next, rows=None
     ):
         state = EngineState(elm=elm, prune=prune, drift=drift, meter=meter)
-        state = fleet.learn(
-            state, h, labels, pred, conf, mask, controller_on, cfg, theta=theta
+        state = fleet.learn_rows(
+            state, rows, h, labels, pred, conf, mask, controller_on, cfg, theta=theta
         )
         new_state, p = fleet.plan(state, x_next, cfg, mode=mode)
         return (new_state.elm, new_state.prune, new_state.drift, new_state.meter), p
@@ -546,11 +571,11 @@ def _learn_plan_avail_runner(cfg: EngineConfig, mode: str, donate: bool):
 
     def run_learn_plan(
         elm, prune, drift, meter, h, labels, pred, conf, mask, controller_on, theta,
-        x_next, avail
+        x_next, avail, rows=None
     ):
         state = EngineState(elm=elm, prune=prune, drift=drift, meter=meter)
-        state = fleet.learn(
-            state, h, labels, pred, conf, mask, controller_on, cfg, theta=theta
+        state = fleet.learn_rows(
+            state, rows, h, labels, pred, conf, mask, controller_on, cfg, theta=theta
         )
         new_state, p = fleet.plan(
             state, x_next, cfg, mode=mode, teacher_available=avail
@@ -558,6 +583,72 @@ def _learn_plan_avail_runner(cfg: EngineConfig, mode: str, donate: bool):
         return (new_state.elm, new_state.prune, new_state.drift, new_state.meter), p
 
     return jax.jit(run_learn_plan, donate_argnums=(0, 1, 2, 3) if donate else ())
+
+
+# Row capacities of a compacted learn: powers of two from this floor up to
+# half the width.  A width below twice the floor (a backbone's decode
+# batch) has none, and every learn there runs at full width.
+LEARN_ROWS_FLOOR = 256
+
+
+def learn_capacities(s: int) -> tuple:
+    """The row capacities K of a compacted learn at width ``s``."""
+    ks, k = [], LEARN_ROWS_FLOOR
+    while 2 * k <= s:
+        ks.append(k)
+        k *= 2
+    return tuple(ks)
+
+
+def learn_rows_index(mask: np.ndarray) -> Optional[np.ndarray]:
+    """The rows one reply's learn runs over, for its host-side apply mask.
+
+    The smallest capacity K of ``learn_capacities`` that holds the ``n``
+    answered rows, and the sorted, unique int32 index of length K of those
+    rows padded with the first K - n rows outside the mask (which learn as
+    the identity and are written back as they were).  None — learn at full
+    width — when no capacity holds them: more than half the rows answered,
+    or a width below twice the floor.
+    """
+    n = int(mask.sum())
+    k = next((k for k in learn_capacities(mask.shape[0]) if k >= n), None)
+    if k is None:
+        return None
+    sel = np.array(mask, bool)
+    sel[np.flatnonzero(~sel)[: k - n]] = True
+    return np.flatnonzero(sel).astype(np.int32)
+
+
+def _spec(a) -> jax.ShapeDtypeStruct:
+    """A runner argument's abstract value as jit keys its executables: the
+    shape, the dtype, and the sharding of an array committed to a device."""
+    sharding = a.sharding if getattr(a, "committed", False) else None
+    return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding)
+
+
+@functools.lru_cache(maxsize=fleet.RUNNER_CACHE_SIZE)
+def _compile_ladder(fn, specs: tuple, s: int) -> None:
+    """Compile the learn runner ``fn`` at every capacity of width ``s``
+    (``learn_capacities``) for arguments ``specs``: jit then finds each
+    executable when a reply first needs it, and no reply compiles."""
+    for k in learn_capacities(s):
+        fn.lower(*specs, rows=jax.ShapeDtypeStruct((k,), jnp.int32)).compile()
+
+
+def learn_call(fn, args: tuple, rows: Optional[np.ndarray], s: int,
+               laddered: set, ship: Callable):
+    """One learn dispatch of a session of width ``s``: ``fn(*args)`` at
+    full width when ``rows`` is None, else over the rows of the host index
+    ``rows`` (``learn_rows_index``).  A session's first compacted call of
+    ``fn`` at a width compiles the width's capacities (``_compile_ladder``;
+    ``laddered`` records it), so that a capacity first met later in the
+    run compiles nothing."""
+    if rows is None:
+        return fn(*args)
+    if (fn, s) not in laddered:
+        _compile_ladder(fn, jax.tree.map(_spec, args), s)
+        laddered.add((fn, s))
+    return fn(*args, rows=ship(rows))
 
 
 def cache_stats() -> dict:
@@ -569,6 +660,7 @@ def cache_stats() -> dict:
         ("learn_plan_runner", _learn_plan_runner),
         ("plan_avail_runner", _plan_avail_runner),
         ("learn_plan_avail_runner", _learn_plan_avail_runner),
+        ("compile_ladder", _compile_ladder),
     ):
         info = fn.cache_info()
         out[name] = {
@@ -649,17 +741,18 @@ class StreamSession:
         # is dead padding (a sharded session's S-rounding, see
         # ShardedStreamSession).  Dead rows plan with teacher_available=False
         # (never query/learn) and are excluded from ``stream_steps``.
-        self.live = None if live is not None and live >= jax.tree.leaves(state)[0].shape[0] else live
+        self.width = int(jax.tree.leaves(state)[0].shape[0])
+        self.live = None if live is not None and live >= self.width else live
         self._avail = None  # device (S,) bool, built lazily at start()
         if self.live is None:
             self._plan_fn = _plan_runner(cfg, mode, donate)
             self._fused_fn = _learn_plan_runner(cfg, mode, donate)
         else:
             plan_raw = _plan_avail_runner(cfg, mode, donate)
-            fused_raw = _learn_plan_avail_runner(cfg, mode, donate)
             self._plan_fn = lambda *a: plan_raw(*a, self._avail)
-            self._fused_fn = lambda *a: fused_raw(*a, self._avail)
+            self._fused_fn = _learn_plan_avail_runner(cfg, mode, donate)
         self._learn_fn = _learn_runner(cfg, donate)
+        self._laddered: set = set()  # (runner, width) compiled by learn_call
         # ``block``: asks waiting for a ring slot (bounded like the ring;
         # overflow drops the oldest deferred ask, metered).
         self._deferred: "collections.deque[DeferredAsk]" = collections.deque()
@@ -770,15 +863,20 @@ class StreamSession:
             # plan into one dispatch (earlier replies, if any, apply first,
             # so all of tick t's answers land before tick t+1 is planned).
             if applies:
-                for args in applies[:-1]:
-                    self._learn(args, tel)
+                for args, rows in applies[:-1]:
+                    self._learn(args, rows, tel)
+                args, rows = applies[-1]
+                tail = (nxt,) if self.live is None else (nxt, self._avail)
                 tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-                (elm2, prune2, drift2, meter2), p_next = self._fused_fn(
-                    self.state.elm, self.state.prune, self.state.drift,
-                    self.state.meter, *applies[-1], nxt,
+                (elm2, prune2, drift2, meter2), p_next = self._learn_call(
+                    self._fused_fn,
+                    (self.state.elm, self.state.prune, self.state.drift,
+                     self.state.meter, *args, *tail),
+                    rows,
                 )
                 if tok is not None:
-                    tel.tracer.end(tok, fn="learn_plan")
+                    tel.tracer.end(tok, fn="learn_plan",
+                                   rows=self.width if rows is None else len(rows))
                 self.state = EngineState(
                     elm=elm2, prune=prune2, drift=drift2, meter=meter2
                 )
@@ -794,8 +892,8 @@ class StreamSession:
                     prune=new_prune, drift=new_drift, meter=new_meter
                 )
         else:
-            for args in applies:
-                self._learn(args, tel)
+            for args, rows in applies:
+                self._learn(args, rows, tel)
             p_next = None
         self.stats.ticks += 1
         self.stats.stream_steps += (
@@ -899,9 +997,9 @@ class StreamSession:
     def _poll_and_apply(self) -> list[TeacherReply]:
         replies = self.teacher.poll(self.t)
         for reply in replies:
-            args = self._claim(reply, self.t)
-            if args is not None:
-                self._learn(args)
+            claimed = self._claim(reply, self.t)
+            if claimed is not None:
+                self._learn(*claimed)
         return replies
 
     # odlint: shard-local
@@ -1107,24 +1205,36 @@ class StreamSession:
         )
 
     def _claim(self, reply: TeacherReply, now: int):
-        """Resolve a reply against the ring; returns learn args or None,
+        """Resolve a reply against the ring; returns ``(learn args, rows)``
+        — ``rows`` its ``learn_rows_index``, metered in the stats — or None,
         with all drop/orphan/loss accounting applied."""
         claimed = self._claim_entry(reply, now)
         if claimed is None:
             return None
         ent, mask = claimed
-        return self._build_learn_args(ent, reply, mask)
+        rows = learn_rows_index(mask)
+        self.stats.count_learn(
+            self.width if rows is None else len(rows), rows is not None
+        )
+        return self._build_learn_args(ent, reply, mask), rows
 
-    def _learn(self, args, tel=None) -> None:
-        """One unfused learn dispatch; inside a tick its caller passes the
-        enabled telemetry (``tel``) to record it as ``tick.dispatch``."""
+    def _learn_call(self, fn, args: tuple, rows: Optional[np.ndarray]):
+        return learn_call(fn, args, rows, self.width, self._laddered, self.ship)
+
+    def _learn(self, args, rows: Optional[np.ndarray], tel=None) -> None:
+        """One unfused learn dispatch over ``rows`` (``_claim``); inside a
+        tick its caller passes the enabled telemetry (``tel``) to record it
+        as ``tick.dispatch``."""
         tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-        new_elm, new_prune = self._learn_fn(
-            self.state.elm, self.state.prune, self.state.drift, self.state.meter,
-            *args
+        new_elm, new_prune = self._learn_call(
+            self._learn_fn,
+            (self.state.elm, self.state.prune, self.state.drift, self.state.meter,
+             *args),
+            rows,
         )
         if tok is not None:
-            tel.tracer.end(tok, fn="learn")
+            tel.tracer.end(tok, fn="learn",
+                           rows=self.width if rows is None else len(rows))
         self.state = self.state._replace(elm=new_elm, prune=new_prune)
 
 
@@ -1161,9 +1271,11 @@ def run(
 
     ``donate`` (default True) lets every per-tick dispatch update P/beta
     and the controller leaves in place instead of allocating fresh buffers
-    (P is the dominant one at S·N²·4 bytes/tick).  The runtime first takes
-    ownership of ``state`` with a one-time copy, so the caller's pytree
-    stays valid either way.
+    (P, S·N²·4 bytes, is the dominant one; a reply whose answered rows fit
+    a capacity of ``learn_capacities`` reads and writes only those K rows
+    of it, a full-width reply all S).  The runtime first takes ownership of
+    ``state`` with a one-time copy, so the caller's pytree stays valid
+    either way.
     """
     sess = StreamSession(
         state, cfg, teacher, mode=mode, capacity=capacity,
@@ -1354,6 +1466,7 @@ def aggregate_stats(stats_list: list, padded_streams: int = 0) -> dict:
         "tickets_dropped", "queries_dropped", "replies_orphaned",
         "tickets_lost", "queries_lost", "tickets_coalesced",
         "queries_coalesced", "asks_deferred", "tickets_reasked",
+        "learns_compact", "learns_full", "learn_rows",
     )
     out = {k: sum(getattr(s, k) for s in stats_list) for k in counters}
     out["ticks"] = max((s.ticks for s in stats_list), default=0)

@@ -6,8 +6,11 @@ framework (DESIGN.md §3): every backbone carries an ODL head —
   train_step: backbone CE loss -> grads -> AdamW, PLUS the OS-ELM head
     trained by rank-k RLS on pooled features with P1P2 pruning deciding
     which rows may skip the teacher (label) entirely.  The pruning mask
-    feeds the masked RLS update, so a skipped sample costs zero compute
-    and zero label traffic — the paper's comm saving, fused into the step.
+    feeds the masked RLS update, so a skipped sample costs zero label
+    traffic — the paper's comm saving, fused into the step.  It is still
+    computed under the mask; on the serving fleet path a skipped row costs
+    no P traffic when its reply's learn compacts to the answered rows
+    (``engine.fleet.learn_rows``).
 
   serve_step: one decode token, plus the head's prediction and the
     P1P2/auto-theta gate per stream.  The gate's output (a ``GateOutput``

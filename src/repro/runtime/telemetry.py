@@ -385,6 +385,7 @@ STREAM_COUNTER_FIELDS = (
     "tickets_lost", "queries_lost",
     "tickets_coalesced", "queries_coalesced",
     "asks_deferred", "tickets_reasked",
+    "learns_compact", "learns_full", "learn_rows",
 )
 
 # Load signals: not monotonic, exported as gauges.

@@ -146,6 +146,45 @@ def test_fused_matches_unfused_and_solo_bit_for_bit(sched, quantum):
             assert r.stats.reconciled, r.stats.summary()
 
 
+def test_fused_cohort_wide_enough_to_compact_matches_solo_bit_for_bit():
+    """Two tenants each narrower than twice the floor learn at full width
+    alone; stacked, their fused learns run over the answered rows alone
+    (``stream.learn_rows_index``) — and states, outputs and every
+    deterministic counter are still the solo runs', bit for bit."""
+    cfg = _cfg()
+    width = stream.LEARN_ROWS_FLOOR + 64
+    datas = []
+    for i in range(2):
+        xs, ys = _stream_data(cfg, 50, width, seed=90 + i)
+        ys = ys.copy()
+        ys[:, np.arange(width) % 4 != 0] = 0  # three in four streams go quiet
+        datas.append((xs, ys))
+
+    def teacher(ys, i):
+        return stream.LatencyTeacher(
+            stream.array_labels(ys), latency=1, jitter=1, loss_prob=0.1,
+            partial_prob=0.15, seed=95 + i,
+        )
+
+    solo = [_solo(cfg, xs, teacher(ys, i), capacity=8)
+            for i, (xs, ys) in enumerate(datas)]
+    mux = multiplex.Multiplexer(
+        [_tenant(f"t{i}", cfg, xs, teacher(ys, i), capacity=8)
+         for i, (xs, ys) in enumerate(datas)],
+        quantum=2, fuse=True)
+    assert mux.round()
+    assert any(u.slots for u in mux._cohorts.values())
+    fused, _ = mux.run()
+
+    for i, (st, outs, stats) in enumerate(solo):
+        r = fused[f"t{i}"]
+        assert stats.learns_compact == 0 < r.stats.learns_compact
+        _assert_state_equal(st, r.state, msg=f"t{i}")
+        _assert_outputs_equal(outs, r.outputs, msg=f"t{i}")
+        _assert_stats_equal(stats, r.stats, msg=f"t{i}")
+        assert r.stats.reconciled, r.stats.summary()
+
+
 # ---------------------------------------------------------------------------
 # Mixed shapes: separate cohorts, same results
 # ---------------------------------------------------------------------------

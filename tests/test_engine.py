@@ -189,3 +189,54 @@ def test_broadcast_and_slice_roundtrip():
     back = engine.stream_slice(fleet, 1)
     for a, b in zip(jax.tree.leaves(scalar), jax.tree.leaves(back)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize(
+    "s_len,n",
+    [(1024, 0), (1024, 1), (4096, 328), (2048, 1023), (2048, 1025)],
+    ids=["none", "one", "8pct", "under-half", "over-half"],
+)
+def test_learn_over_compacted_rows_is_full_width_learn_row_for_row(s_len, n):
+    """A reply's learn over its ``learn_rows_index`` == the full-width
+    learn, every leaf bit for bit, and rows outside the mask (padding
+    included) unchanged; the index is sorted, unique, of the smallest
+    capacity that holds the answered rows, and holds every one of them —
+    or None (full width) past half the rows."""
+    from repro.engine import stream
+
+    cfg = _cfg()
+    xs, ys = _stream_data(cfg, 7, s_len, seed=9)
+    st, _ = engine.run_fleet(
+        engine.init_fleet(cfg, s_len), xs[:6], ys[:6], cfg, mode="train_phase"
+    )
+    st, p = engine.plan(st, xs[6], cfg, mode="serve")
+    rng = np.random.default_rng(n)
+    mask = np.zeros(s_len, bool)
+    mask[rng.choice(s_len, n, replace=False)] = True
+    labels = rng.integers(0, cfg.elm.n_out, s_len).astype(np.int32)
+
+    rows = stream.learn_rows_index(mask)
+    if 2 * n > s_len:
+        assert rows is None
+    else:
+        k = min(c for c in stream.learn_capacities(s_len) if c >= n)
+        assert rows.dtype == np.int32 and len(rows) == k
+        assert np.all(np.diff(rows) > 0)  # sorted and unique
+        assert np.isin(np.flatnonzero(mask), rows).all()
+        pad = rows[~mask[rows]]
+        np.testing.assert_array_equal(pad, np.flatnonzero(~mask)[: k - n])
+
+    runner = stream._learn_runner(cfg, False)
+    args = (st.elm, st.prune, st.drift, st.meter, p.h, labels, p.pred,
+            p.confidence, jnp.asarray(mask), p.controller_on, p.theta)
+    full = runner(*args)
+    compact = runner(*args) if rows is None else runner(*args, rows=rows)
+    for (path, a), (_, b), (_, before) in zip(
+        jax.tree_util.tree_flatten_with_path(full)[0],
+        jax.tree_util.tree_flatten_with_path(compact)[0],
+        jax.tree_util.tree_flatten_with_path((st.elm, st.prune))[0],
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=str(path))
+        np.testing.assert_array_equal(
+            np.asarray(b)[~mask], np.asarray(before)[~mask], err_msg=str(path)
+        )

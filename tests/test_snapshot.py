@@ -2,6 +2,7 @@
 backpressure policy, kill-mid-write recovery, crash-restart supervision, and
 live tenant migration with the query-accounting identity intact."""
 
+import json
 import os
 
 import jax
@@ -22,6 +23,7 @@ DETERMINISTIC_STATS = (
     "labels_applied", "tickets_dropped", "queries_dropped",
     "replies_orphaned", "tickets_lost", "queries_lost",
     "tickets_coalesced", "queries_coalesced", "asks_deferred",
+    "learns_compact", "learns_full", "learn_rows",
 )
 
 
@@ -130,6 +132,38 @@ def test_resume_parity_bit_for_bit(policy, tmp_path):
             np.asarray(getattr(outs2, name)),
             err_msg=f"{policy}: output {name!r} diverged",
         )
+
+
+def test_learn_counters_ride_the_snapshot_and_default_to_zero_when_absent():
+    """The learn-dispatch counters travel in a snapshot, over the wire too;
+    a snapshot written before they existed restores them at 0."""
+    cfg = _cfg()
+    s_len = 2 * stream.LEARN_ROWS_FLOOR
+    xs, ys = _stream_data(cfg, 12, s_len, seed=8)
+
+    def teacher():  # most of each ticket unanswered: the learns compact
+        return stream.LatencyTeacher(stream.array_labels(ys), latency=1,
+                                     partial_prob=0.6, seed=4)
+
+    sess = stream.StreamSession(engine.init_fleet(cfg, s_len), cfg, teacher(),
+                                mode="train_phase", capacity=4)
+    sess.start(xs[0])
+    for x in xs[1:]:
+        sess.advance(x)
+    fields = ("learns_compact", "learns_full", "learn_rows")
+    assert sess.stats.learns_compact > 0
+    tree = snapshot.decode_snapshot(snapshot.encode_snapshot(sess.snapshot()))
+    back = stream.StreamSession.restore(tree, teacher(), cfg=cfg)
+    for f in fields:
+        assert getattr(back.stats, f) == getattr(sess.stats, f), f
+
+    meta = json.loads(np.asarray(tree["meta"]).item())
+    for f in fields:
+        del meta["stats"][f]
+    old = stream.StreamSession.restore(
+        {**tree, "meta": np.asarray(json.dumps(meta))}, teacher(), cfg=cfg)
+    assert all(getattr(old.stats, f) == 0 for f in fields)
+    assert old.stats.labels_applied == sess.stats.labels_applied
 
 
 def test_restore_without_teacher_state_reasks_in_flight(tmp_path):

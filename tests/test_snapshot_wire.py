@@ -3,6 +3,8 @@ v2 binary frame (``engine.snapshot.encode_snapshot``) and decoded back must
 be bitwise identical; truncated/corrupted/mis-versioned frames must be
 rejected loudly — a migration must never restore silently-corrupt state."""
 
+import json
+
 import numpy as np
 import pytest
 
@@ -90,6 +92,9 @@ def test_roundtrip_real_session_snapshot():
     wire = snapshot.encode_snapshot(tree)
     assert isinstance(wire, bytes) and wire[0] == rpc.WIRE_V2
     _assert_trees_bitwise(tree, snapshot.decode_snapshot(wire))
+    meta = json.loads(np.asarray(snapshot.decode_snapshot(wire)["meta"]).item())
+    assert meta["stats"]["learns_full"] == sess.stats.learns_full > 0
+    assert meta["stats"]["learn_rows"] == sess.stats.learn_rows
     # In-flight ring state really was mid-flight (the interesting case).
     assert len(sess.ring) > 0
 

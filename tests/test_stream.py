@@ -25,24 +25,39 @@ def _cfg(n_in=24, n_hidden=16, n_out=4, min_trained=16):
     )
 
 
-def _stream_data(cfg, t, s, seed=0, shift_at=None):
+def _stream_data(cfg, t, s, seed=0, shift_at=None, calm=None):
+    """Random features and labels; with ``shift_at`` the features drift
+    from that tick on.  ``calm`` picks streams that never drift and always
+    see class 0, so their heads grow confident and stop querying: the
+    query rate then falls well below every stream."""
     kx, ky = jax.random.split(jax.random.PRNGKey(seed))
     xs = np.array(jnp.tanh(jax.random.normal(kx, (t, s, cfg.elm.n_in))))
+    ys = np.array(jax.random.randint(ky, (t, s), 0, cfg.elm.n_out), np.int32)
+    drifting = np.ones(s, bool)
+    if calm is not None:
+        drifting[calm] = False
+        ys[:, calm] = 0
     if shift_at is not None:
-        sev = np.linspace(2.0, 4.0, s)[None, :, None]
-        xs[shift_at:] = np.clip(xs[shift_at:] * sev + 0.5 * sev, -4, 4)
-    ys = np.asarray(jax.random.randint(ky, (t, s), 0, cfg.elm.n_out), np.int32)
+        sev = np.linspace(2.0, 4.0, int(drifting.sum()))[None, :, None]
+        xs[shift_at:, drifting] = np.clip(
+            xs[shift_at:, drifting] * sev + 0.5 * sev, -4, 4)
     return jnp.asarray(xs), ys
 
 
-@pytest.mark.parametrize("mode", ["algo1", "train_phase"])
-def test_zero_latency_matches_run_fleet_bit_for_bit(mode):
+@pytest.mark.parametrize("mode,s_len,calm", [
+    pytest.param("algo1", 3, None, id="algo1"),
+    pytest.param("train_phase", 3, None, id="train_phase"),
+    pytest.param("algo1", 512, slice(128, None), id="algo1-compact"),
+    pytest.param("train_phase", 512, slice(128, None), id="train_phase-compact"),
+])
+def test_zero_latency_matches_run_fleet_bit_for_bit(mode, s_len, calm):
     """stream.run with an instant teacher IS run_fleet: every output field
     and every leaf of the final state must match bit-for-bit (plan/learn
-    are the exact two halves of fleet_step)."""
+    are the exact two halves of fleet_step) — also where few streams query
+    and the learns run over the answered rows alone."""
     cfg = _cfg()
-    t_len, s_len = 90, 3
-    xs, ys = _stream_data(cfg, t_len, s_len, seed=1, shift_at=40)
+    t_len = 90
+    xs, ys = _stream_data(cfg, t_len, s_len, seed=1, shift_at=40, calm=calm)
 
     st_f, out_f = engine.run_fleet(
         engine.init_fleet(cfg, s_len), xs, jnp.asarray(ys), cfg, mode=mode
@@ -70,6 +85,7 @@ def test_zero_latency_matches_run_fleet_bit_for_bit(mode):
     assert stats.labels_applied == stats.queries_issued > 0
     assert stats.tickets_dropped == stats.tickets_lost == stats.replies_orphaned == 0
     assert stats.label_latency_p95 == 0.0
+    assert (stats.learns_compact > 0) == (s_len >= 2 * stream.LEARN_ROWS_FLOOR)
 
 
 def test_zero_latency_matches_run_fleet_on_the_har_protocol():
@@ -133,6 +149,74 @@ def test_deferred_out_of_order_labels_train_on_query_time_features():
     lat = np.asarray(stats.label_latency_ticks)
     assert lat.min() >= 2 and lat.max() > lat.min()
     assert stats.tickets_lost == 0 and len(teacher._inbox) == 0
+
+
+def test_compacted_learns_under_a_laggy_teacher_match_full_width_sessions():
+    """Replies landing late, out of order, lost, and two to a tick: a
+    512-stream session, whose learns run over the answered rows alone, ends
+    bit for bit where two 256-stream sessions over the same rows end —
+    their width is below twice the floor, so they learn at full width."""
+    cfg = _cfg()
+    t_len, s_len = 60, 2 * stream.LEARN_ROWS_FLOOR
+    calm = np.arange(s_len) % 4 != 0  # every fourth stream keeps querying
+    xs, ys = _stream_data(cfg, t_len, s_len, seed=3, calm=calm)
+
+    def run(lo, hi):
+        teacher = stream.LatencyTeacher(
+            stream.array_labels(ys[:, lo:hi]), latency=2, jitter=2,
+            loss_prob=0.1, seed=5,
+        )
+        return stream.run(
+            engine.init_fleet(cfg, hi - lo), (xs[t, lo:hi] for t in range(t_len)),
+            cfg, teacher, mode="train_phase",
+        )
+
+    st_w, out_w, stats_w = run(0, s_len)
+    halves = [run(0, s_len // 2), run(s_len // 2, s_len)]
+    assert stats_w.learns_compact > 0 and stats_w.learns_full > 0
+    assert stats_w.learn_rows < s_len * (stats_w.learns_compact + stats_w.learns_full)
+    assert all(h[2].learns_compact == 0 for h in halves)
+    assert stats_w.labels_applied == sum(h[2].labels_applied for h in halves)
+    for a, *parts in zip(jax.tree.leaves(st_w), *(jax.tree.leaves(h[0]) for h in halves)):
+        np.testing.assert_array_equal(np.asarray(a), np.concatenate(parts))
+    for name in out_w._fields:
+        np.testing.assert_array_equal(
+            getattr(out_w, name),
+            np.concatenate([getattr(h[1], name) for h in halves], axis=1),
+            err_msg=name,
+        )
+
+
+def test_padded_session_compacts_through_the_avail_runner():
+    """A session whose tail rows are dead padding (``live``, as a sharded
+    session's tail shard has) plans with ``teacher_available`` and learns
+    over compacted rows: its live rows end bit for bit where a session of
+    just those rows ends, whose width is too small to compact."""
+    cfg = _cfg()
+    t_len, live, s_len = 60, 500, 2 * stream.LEARN_ROWS_FLOOR
+    xs, ys = _stream_data(cfg, t_len, s_len, seed=4, calm=np.arange(s_len) % 4 != 0)
+
+    def run(width, **kw):
+        teacher = stream.LatencyTeacher(
+            stream.array_labels(ys[:, :width]), latency=2, jitter=2,
+            loss_prob=0.1, seed=6,
+        )
+        sess = stream.StreamSession(engine.init_fleet(cfg, width), cfg, teacher,
+                                    mode="train_phase", **kw)
+        sess.start(xs[0, :width])
+        for t in range(1, t_len):
+            sess.advance(xs[t, :width])
+        sess.advance(None)
+        return sess.finish()
+
+    st_p, out_p, stats_p = run(s_len, live=live)
+    st_l, out_l, stats_l = run(live)
+    assert stats_p.learns_compact > 0 and stats_l.learns_compact == 0
+    assert stats_p.stream_steps == stats_l.stream_steps == t_len * live
+    for a, b in zip(jax.tree.leaves(st_p), jax.tree.leaves(st_l)):
+        np.testing.assert_array_equal(np.asarray(a)[:live], np.asarray(b))
+    np.testing.assert_array_equal(out_p.queried[:, :live], out_l.queried)
+    assert not out_p.queried[:, live:].any()
 
 
 def test_ring_overflow_drops_oldest_and_meters_it():

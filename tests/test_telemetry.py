@@ -304,6 +304,9 @@ def test_all_stream_stats_counters_are_mirrored():
     )
     fields = {f.name for f in dataclasses.fields(stream.StreamStats)}
     assert fields - excluded == set(telemetry.STREAM_COUNTER_FIELDS)
+    assert {"learns_compact", "learns_full", "learn_rows"} <= set(
+        telemetry.STREAM_COUNTER_FIELDS
+    )
     assert set(telemetry.STREAM_GAUGE_FIELDS) < fields
     # the three partitions are disjoint
     assert not set(telemetry.STREAM_MIRROR_EXCLUDED) & set(

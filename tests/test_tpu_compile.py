@@ -10,12 +10,15 @@ imported or collected.  The persistent compile cache is off around these
 compiles (an entry compiled for a described chip cannot be read back).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs, engine
+from repro.configs import har_odl
 from repro.engine import stream
 from repro.kernels import oselm_update, xorshift_proj
 from repro.models import model as model_lib
@@ -122,3 +125,34 @@ def test_learn_plan_runner_compiles_at_backbone_width(one_chip):
     compiled = runner.lower(*args).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 0  # the head state is donated in place
+
+
+def test_compacted_learn_plan_updates_p_in_place(one_chip):
+    """The learn+plan runner at the HAR cell's width (S 65536, n_in 561,
+    N 128) over a compacted 8192-row index: the scatter writes into the
+    donated P, so the program holds no P-sized copy, and its temporaries
+    are a fraction of P."""
+    s, k = 65536, 8192
+    cfg = har_odl.full()
+    n_in = cfg.elm.n_in
+    assert (n_in, cfg.elm.n_hidden) == (561, N)
+    st = jax.eval_shape(lambda: engine.init_fleet(cfg, s))
+
+    def v(dt):
+        return jax.ShapeDtypeStruct((s,), dt)
+
+    learn_args = (jax.ShapeDtypeStruct((s, N), jnp.float32), v(jnp.int32),
+                  v(jnp.int32), v(jnp.float32), v(jnp.bool_), v(jnp.bool_),
+                  v(jnp.float32))
+    runner = stream._learn_plan_runner(cfg, "serve", True)
+    args = _on(one_chip, (st.elm, st.prune, st.drift, st.meter, *learn_args,
+                          jax.ShapeDtypeStruct((s, n_in), jnp.float32)))
+    rows = _on(one_chip, jax.ShapeDtypeStruct((k,), jnp.int32))
+    compiled = runner.lower(*args, rows=rows).compile()
+    p_shape = f"f32[{s},{N},{N}]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if p_shape in line and re.search(r"\bcopy(-start)?\(", line)]
+    assert not copies, copies[:3]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= s * N * N * 4  # P is donated
+    assert mem.temp_size_in_bytes < s * N * N * 4 / 2
