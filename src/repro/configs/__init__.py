@@ -16,6 +16,7 @@ from repro.configs.base import (  # noqa: F401
 ARCH_IDS = (
     "deepseek-moe-16b",
     "deepseek-v2-236b",
+    "deepseek-v2-lite",
     "h2o-danube-1.8b",
     "deepseek-coder-33b",
     "mistral-nemo-12b",

@@ -41,6 +41,16 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
+    first_k_dense: int = 0  # leading dense layers (width d_ff) before the MoE stack
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0
+    # The expert share: this chip holds routed experts [expert_first,
+    # expert_first + experts_held) of the router's n_experts and computes
+    # their part of each routed layer for every token routed to them, with
+    # no capacity and no token dropped (``moe.share_block``).  0 keeps the
+    # capacity-bounded layer over every expert (``moe_impl``).
+    experts_held: int = 0
+    expert_first: int = 0
     # 'dense': pjit scatter-dispatch (XLA SPMD replicates it — the measured
     # baseline); 'ep': explicit shard_map expert parallelism with
     # all-to-all dispatch (hillclimb variant, EXPERIMENTS.md §Perf H1).
@@ -48,11 +58,19 @@ class ModelConfig:
 
     # --- MLA (deepseek-v2) ---
     use_mla: bool = False
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536  # None: queries straight from d_model (wq)
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    # YaRN rope scaling of the latent attention's rope part (DeepSeek-V2's
+    # "rope_scaling": {"type": "yarn", ...}); factor 0 is plain rope.
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM (mamba2 SSD) ---
     ssm_state: int = 0

@@ -1,9 +1,11 @@
-"""deepseek-v2-236b [moe] — MLA (kv_lora=512) + 2 shared + 160 routed top-6.
+"""deepseek-v2-236b [moe] — MLA (kv_lora=512) + 2 shared + 160 routed top-6,
+YaRN rope scaling (factor 40 over 4096 positions, mscale 0.707).
 
 60L d_model=5120 128H d_ff_expert=1536 vocab=102400 [arXiv:2405.04434; hf]
 """
 
 from repro.configs.base import ModelConfig
+from repro.configs.deepseek_v2_lite import YARN
 
 
 def full() -> ModelConfig:
@@ -27,6 +29,7 @@ def full() -> ModelConfig:
         qk_nope_dim=128,
         qk_rope_dim=64,
         v_head_dim=128,
+        **YARN,
     )
 
 

@@ -82,13 +82,19 @@ from repro.runtime import accel, telemetry
 
 def _decode_feats(params, state, prompts, cfg, gen_tokens):
     """Tick source: one backbone decode step per tick, yielding (B, d)
-    stream features (greedy next-token feedback, ODL state untouched)."""
+    stream features (greedy next-token feedback, ODL state untouched).
+    When it ends, a backbone with an expert share exports its per-layer
+    expert counters to the telemetry registry."""
     step = jax.jit(lambda p, st, t: model_lib.decode_step(p, st, t, cfg))
     tok = prompts[:, -1:]
     for _ in range(gen_tokens):
         logits, feats, state = step(params, state, tok)
         tok = jnp.argmax(logits, -1)[:, None]
         yield feats
+    load = model_lib.expert_load(state)
+    tel = telemetry.get()
+    if load is not None and tel is not None:
+        model_lib.sync_expert_counters(tel.registry, load, cfg)
 
 
 def _all_finite(tree) -> bool:

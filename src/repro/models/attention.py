@@ -52,12 +52,18 @@ def gqa_schema(cfg: ModelConfig) -> dict:
 def mla_schema(cfg: ModelConfig) -> dict:
     d, h = cfg.d_model, cfg.n_heads
     qk = cfg.qk_nope_dim
+    if cfg.q_lora_rank is None:
+        q = {"wq": Leaf((d, h, qk + cfg.qk_rope_dim), ("embed", "heads", "head_dim"))}
+    else:
+        q = {
+            "wq_a": Leaf((d, cfg.q_lora_rank), ("embed", None)),
+            "q_a_norm": Leaf((cfg.q_lora_rank,), (None,), init="zeros"),
+            "wq_b": Leaf(
+                (cfg.q_lora_rank, h, qk + cfg.qk_rope_dim), (None, "heads", "head_dim")
+            ),
+        }
     return {
-        "wq_a": Leaf((d, cfg.q_lora_rank), ("embed", None)),
-        "q_a_norm": Leaf((cfg.q_lora_rank,), (None,), init="zeros"),
-        "wq_b": Leaf(
-            (cfg.q_lora_rank, h, qk + cfg.qk_rope_dim), (None, "heads", "head_dim")
-        ),
+        **q,
         "wkv_a": Leaf((d, cfg.kv_lora_rank + cfg.qk_rope_dim), ("embed", None)),
         "kv_a_norm": Leaf((cfg.kv_lora_rank,), (None,), init="zeros"),
         "wkv_b": Leaf(
@@ -283,12 +289,49 @@ def _scatter_cache(buf, new, oh):
 # ---------------------------------------------------------------------------
 
 
+def mla_rope(cfg: ModelConfig) -> tuple:
+    """(inverse frequencies or None, cos/sin scale, softmax scale) of the
+    latent attention: plain rope and 1/sqrt(q head dim), or with YaRN
+    (``yarn_factor``) DeepSeek-V2's frequencies, cos and sin times
+    mscale(mscale) / mscale(mscale_all_dim), and the softmax scale times
+    mscale(mscale_all_dim)^2."""
+    softmax = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if not cfg.yarn_factor:
+        return None, 1.0, softmax
+    f = cfg.yarn_factor
+    freqs = layers.yarn_frequencies(cfg.qk_rope_dim, cfg.rope_theta, f,
+                                    cfg.yarn_original_max_len, cfg.yarn_beta_fast,
+                                    cfg.yarn_beta_slow)
+    scale = layers.yarn_mscale(f, cfg.yarn_mscale) / layers.yarn_mscale(
+        f, cfg.yarn_mscale_all_dim)
+    if cfg.yarn_mscale_all_dim:
+        softmax *= layers.yarn_mscale(f, cfg.yarn_mscale_all_dim) ** 2
+    return freqs, scale, softmax
+
+
+def _mla_rope(x, positions, cfg):
+    freqs, scale, _ = mla_rope(cfg)
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs, scale=scale)
+
+
 def _mla_q(x, p, cfg, positions):
-    q_a = rmsnorm(x @ cast(p["wq_a"]), p["q_a_norm"])
-    q = jnp.einsum("bsr,rhe->bshe", q_a, cast(p["wq_b"]))
+    if "wq" in p:
+        q = jnp.einsum("bsd,dhe->bshe", x, cast(p["wq"]))
+    else:
+        q_a = rmsnorm(x @ cast(p["wq_a"]), p["q_a_norm"])
+        q = jnp.einsum("bsr,rhe->bshe", q_a, cast(p["wq_b"]))
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(q_rope, positions, cfg)
+
+
+def _mla_kv_latent(x, p, cfg, positions):
+    """The compressed latent (B,S,kv_lora), normed, and the shared roped
+    key (B,S,rope_dim) of x: what the cache holds."""
+    kv_a = x @ cast(p["wkv_a"])
+    ckv, k_rope = jnp.split(kv_a, [cfg.kv_lora_rank], axis=-1)
+    ckv = rmsnorm(ckv, p["kv_a_norm"])
+    k_rope = _mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0]
+    return ckv, k_rope
 
 
 def _mla_kv(ckv, p, cfg):
@@ -299,26 +342,25 @@ def _mla_kv(ckv, p, cfg):
 
 def mla_attention(x, p, cfg: ModelConfig, positions, causal: bool = True,
                   return_kv: bool = False):
-    b, s, _ = x.shape
+    """The published (expanded) form: the latent is taken up to per-head
+    keys and values.  Prefill and training run it; decode runs the absorbed
+    form over the cache (``mla_decode``)."""
     q_nope, q_rope = _mla_q(x, p, cfg, positions)
-    kv_a = x @ cast(p["wkv_a"])
-    ckv, k_rope = jnp.split(kv_a, [cfg.kv_lora_rank], axis=-1)
-    ckv = rmsnorm(ckv, p["kv_a_norm"])
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # shared head
+    ckv, k_rope = _mla_kv_latent(x, p, cfg, positions)
     k_nope, v = _mla_kv(ckv, p, cfg)
 
     bias = _mask_bias(positions, positions, causal, None)
-    scale = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = mla_rope(cfg)[2]
     scores = (
         jnp.einsum("bqhe,bkhe->bhqk", q_nope, k_nope, preferred_element_type=jnp.float32)
-        + jnp.einsum("bqhe,bke->bhqk", q_rope, k_rope[:, :, 0], preferred_element_type=jnp.float32)
+        + jnp.einsum("bqhe,bke->bhqk", q_rope, k_rope, preferred_element_type=jnp.float32)
     ) * scale + bias[:, 0]
     probs = jax.nn.softmax(scores, -1).astype(v.dtype)
     o = jnp.einsum("bhqk,bkhe->bqhe", probs, v, preferred_element_type=jnp.float32)
     o = o.astype(x.dtype)
     out = jnp.einsum("bshe,hed->bsd", o, cast(p["wo"]))
     if return_kv:
-        return out, (ckv, k_rope[:, :, 0])
+        return out, (ckv, k_rope)
     return out
 
 
@@ -330,14 +372,14 @@ def mla_init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=layers.COMP
 
 
 def mla_decode(x, p, cfg: ModelConfig, cache, pos):
-    b = x.shape[0]
+    """One decode step over the latent cache with the up-projections
+    absorbed: the query's nope part is taken through W_UK into the latent,
+    scores are read against the cached latent and roped key directly, the
+    values are summed in the latent and taken out through W_UV and wo.  The
+    cache is read once and never expanded to per-head keys or values."""
     max_len = cache["ckv"].shape[1]
     q_nope, q_rope = _mla_q(x, p, cfg, pos[:, None])
-
-    kv_a = x @ cast(p["wkv_a"])
-    ckv_new, k_rope_new = jnp.split(kv_a, [cfg.kv_lora_rank], axis=-1)
-    ckv_new = rmsnorm(ckv_new, p["kv_a_norm"])
-    k_rope_new = apply_rope(k_rope_new[:, :, None, :], pos[:, None], cfg.rope_theta)[:, :, 0]
+    ckv_new, k_rope_new = _mla_kv_latent(x, p, cfg, pos[:, None])
 
     if cfg.cache_update == "dus":
         ckv = jax.lax.dynamic_update_slice(
@@ -351,17 +393,22 @@ def mla_decode(x, p, cfg: ModelConfig, cache, pos):
         ckv = _scatter_flat(cache["ckv"], ckv_new, oh)
         k_rope = _scatter_flat(cache["k_rope"], k_rope_new, oh)
 
-    k_nope, v = _mla_kv(ckv.astype(x.dtype), p, cfg)
-    k_pos = jnp.broadcast_to(jnp.arange(max_len)[None], (b, max_len))
-    valid = k_pos <= pos[:, None]
-    bias = _mask_bias(pos[:, None], k_pos, causal=False, window=None, k_valid=valid)
-    scale = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
-    scores = (
-        jnp.einsum("bqhe,bkhe->bhqk", q_nope, k_nope, preferred_element_type=jnp.float32)
-        + jnp.einsum("bqhe,bke->bhqk", q_rope, k_rope.astype(x.dtype), preferred_element_type=jnp.float32)
-    ) * scale + bias[:, 0]
-    probs = jax.nn.softmax(scores, -1).astype(x.dtype)
-    o = jnp.einsum("bhqk,bkhe->bqhe", probs, v)
+    w_uk, w_uv = jnp.split(cast(p["wkv_b"]), [cfg.qk_nope_dim], axis=-1)
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bqhe,rhe->bqhr", q_nope, w_uk).astype(x.dtype)
+    with jax.named_scope("mla.latent_attn"):
+        valid = jnp.arange(max_len)[None] <= pos[:, None]  # (B, S_max)
+        scores = (
+            jnp.einsum("bqhr,bkr->bhqk", q_lat, ckv.astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("bqhe,bke->bhqk", q_rope, k_rope.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        ) * mla_rope(cfg)[2]
+        scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, -1).astype(x.dtype)
+        o_lat = jnp.einsum("bhqk,bkr->bqhr", probs, ckv.astype(x.dtype))
+    with jax.named_scope("mla.absorb"):
+        o = jnp.einsum("bqhr,rhe->bqhe", o_lat.astype(x.dtype), w_uv)
     out = jnp.einsum("bshe,hed->bsd", o.astype(x.dtype), cast(p["wo"]))
     return out, {"ckv": ckv, "k_rope": k_rope}
 

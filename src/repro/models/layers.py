@@ -10,6 +10,7 @@ bookkeeping to drift out of sync.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -161,6 +162,24 @@ def swiglu(x, wg, wu, wd):
     return h @ cast(wd)
 
 
+TOKEN_CHUNK = 8192  # tokens per pass of a wide per-token layer over a long input
+
+
+def token_chunked(fn, x, *per_row, chunk: int = TOKEN_CHUNK):
+    """``fn(x, *per_row)`` on inputs with a leading row axis (tokens, or
+    sequences), over chunks of ``chunk`` rows when there are more rows
+    than that and a multiple of it, so a wide layer's activations stay a
+    chunk's size over a long prefill.  fn works row by row, so the result
+    is the same; its outputs (any pytree) are stacked back in row order."""
+    t = x.shape[0]
+    if t <= chunk or t % chunk:
+        return fn(x, *per_row)
+    n = t // chunk
+    split = lambda a: a.reshape((n, chunk) + a.shape[1:])
+    y = jax.lax.map(lambda args: fn(*args), tuple(split(a) for a in (x,) + per_row))
+    return jax.tree.map(lambda a: a.reshape((t,) + a.shape[2:]), y)
+
+
 def gelu_mlp(x, wi, wo):
     h = jax.nn.gelu(x @ cast(wi), approximate=True)
     h = sharding.constrain(h, "batch", "seq", "mlp")
@@ -176,12 +195,46 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0):
-    """x: (B, S, H, hd); positions: (B, S) int32."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """DeepSeek's ``yarn_get_mscale``: the attention temperature YaRN pairs
+    with a context stretched ``scale`` times."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float, original_max_len: int,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (dim/2,), float32, as DeepSeek-V2 computes
+    them: the plain frequency for dimensions that turn more than
+    ``beta_fast`` times over ``original_max_len`` positions, the frequency
+    divided by ``factor`` for those that turn fewer than ``beta_slow``
+    times, and a linear ramp between (``yarn_find_correction_range``)."""
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp  # 1 where the plain frequency is kept
+    return (extra / factor * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
+               freqs=None, scale: float = 1.0):
+    """x: (B, S, H, hd); positions: (B, S) int32.  ``freqs`` (hd/2,)
+    replaces the plain inverse frequencies (YaRN), and ``scale``
+    multiplies cos and sin (YaRN's mscale ratio)."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)  # (hd/2,)
+    if freqs is None:
+        freqs = rope_frequencies(hd, theta)  # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, hd/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)

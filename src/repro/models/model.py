@@ -31,6 +31,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.core import drift as drift_mod
@@ -268,6 +269,30 @@ def decode_step(
     return logits, feats, state._replace(caches=new_caches, pos=state.pos + 1)
 
 
+def expert_load(state: ServeState) -> Optional[dict]:
+    """The expert share's counters from a decode state, on the host:
+    ``tokens`` and ``rows`` (MoE layers, experts_held) int64, the
+    token-choices routed to each held expert and the rows it computed,
+    padding included, over the decode steps since the last prefill.  None
+    for a backbone without an expert share."""
+    layers_c = state.caches.get("layers", {})
+    if "expert_tokens" not in layers_c:
+        return None
+    return {"tokens": np.asarray(layers_c["expert_tokens"], np.int64),
+            "rows": np.asarray(layers_c["expert_rows"], np.int64)}
+
+
+def sync_expert_counters(registry, load: dict, cfg: ModelConfig, **labels) -> None:
+    """Export ``expert_load`` as registry counters, one series per MoE
+    layer and held expert (labelled with its id among all ``n_experts``):
+    ``odl_backbone_expert_tokens`` and ``odl_backbone_expert_rows``
+    (absolute writes; the decode state is the source of truth)."""
+    for name in ("tokens", "rows"):
+        for (layer, held), v in np.ndenumerate(load[name]):
+            registry.set_counter(f"odl_backbone_expert_{name}", float(v), layer=str(layer),
+                                 expert=str(cfg.expert_first + held), **labels)
+
+
 def serve_apply_labels(
     state: ServeState,
     ctx: engine.GateOutput,  # gate output captured at query time
@@ -353,6 +378,8 @@ def cache_axes(path: tuple, leaf) -> tuple:
         return lead + ("batch", None, "mlp")[: nd - 1]
     if name == "h":  # (L, B, W)
         return lead + ("batch", "mlp")[: nd - 1]
+    if name in ("expert_tokens", "expert_rows"):  # (L, E_held)
+        return lead + (None,)
     return lead + ("batch",) + (None,) * (nd - 2)
 
 

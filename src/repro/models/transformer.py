@@ -27,11 +27,13 @@ from repro.configs.base import ModelConfig
 from repro.distributed import sharding
 from repro.models import attention, moe, rglru, ssm
 from repro.models.layers import (
+    TOKEN_CHUNK,
     Leaf,
     cast,
     rmsnorm,
     stack_schema,
     swiglu,
+    token_chunked,
 )
 
 # ---------------------------------------------------------------------------
@@ -39,14 +41,15 @@ from repro.models.layers import (
 # ---------------------------------------------------------------------------
 
 
-def _mixer_schema(cfg: ModelConfig) -> dict:
+def _mixer_schema(cfg: ModelConfig, dense: bool = False) -> dict:
+    """One layer; ``dense`` gives a MoE config's leading dense layer."""
     if cfg.family == "ssm":
         return {"norm": Leaf((cfg.d_model,), ("embed",), init="zeros"),
                 "ssd": ssm.ssd_schema(cfg)}
     s: dict = {"ln1": Leaf((cfg.d_model,), ("embed",), init="zeros")}
     s["attn"] = attention.mla_schema(cfg) if cfg.use_mla else attention.gqa_schema(cfg)
     s["ln2"] = Leaf((cfg.d_model,), ("embed",), init="zeros")
-    if cfg.n_experts:
+    if cfg.n_experts and not dense:
         s["moe"] = moe.moe_schema(cfg)
     else:
         d, ff = cfg.d_model, cfg.d_ff
@@ -100,7 +103,10 @@ def lm_schema(cfg: ModelConfig) -> dict:
             }
             s["tail"] = stack_schema(tail_group, 1)
     else:
-        s["layers"] = stack_schema(_mixer_schema(cfg), cfg.n_layers)
+        if cfg.first_k_dense:
+            s["dense_layers"] = stack_schema(_mixer_schema(cfg, dense=True),
+                                             cfg.first_k_dense)
+        s["layers"] = stack_schema(_mixer_schema(cfg), cfg.n_layers - cfg.first_k_dense)
     return s
 
 
@@ -116,7 +122,7 @@ def _dense_layer(h, lp, cfg: ModelConfig, positions):
     else:
         h = h + attention.gqa_attention(hn, lp["attn"], cfg, positions)
     hn = rmsnorm(h, lp["ln2"])
-    if cfg.n_experts:
+    if "moe" in lp:
         y, aux = moe.moe_block(hn, lp["moe"], cfg)
         return h + y, aux
     return h + swiglu(hn, lp["mlp"]["wg"], lp["mlp"]["wu"], lp["mlp"]["wd"]), 0.0
@@ -227,13 +233,10 @@ def lm_hidden(
             functools.partial(_ssm_layer, cfg=cfg, positions=positions), remat, unroll,
         )
     else:
-        h, aux = _scan_stack(
-            h,
-            params["layers"],
-            functools.partial(_dense_layer, cfg=cfg, positions=positions),
-            remat,
-            unroll,
-        )
+        layer_fn = functools.partial(_dense_layer, cfg=cfg, positions=positions)
+        if "dense_layers" in params:
+            h, _ = _scan_stack(h, params["dense_layers"], layer_fn, remat, unroll)
+        h, aux = _scan_stack(h, params["layers"], layer_fn, remat, unroll)
 
     h = rmsnorm(h, params["final_norm"])
     h = sharding.constrain(h, "batch", "seq", "embed")
@@ -326,10 +329,15 @@ def lm_prefill(params: dict, tokens: jnp.ndarray, cfg: ModelConfig, max_len=None
 
         def dense_fn(hh, lp):
             hn = rmsnorm(hh, lp["ln1"])
+            # A long prefill runs its sequences in chunks of TOKEN_CHUNK
+            # tokens through the wide parts of a layer, so their activations
+            # (the latent attention's expanded keys, values and scores, a
+            # dense MLP's hidden units) stay a chunk's size.
             if cfg.use_mla:
-                y, (ckv, k_rope) = attention.mla_attention(
-                    hn, lp["attn"], cfg, positions, return_kv=True
-                )
+                y, (ckv, k_rope) = token_chunked(
+                    lambda xc, pc: attention.mla_attention(xc, lp["attn"], cfg, pc,
+                                                           return_kv=True),
+                    hn, positions, chunk=max(1, TOKEN_CHUNK // s))
                 if s == max_len:
                     c = {"ckv": ckv, "k_rope": k_rope}
                 else:
@@ -347,15 +355,24 @@ def lm_prefill(params: dict, tokens: jnp.ndarray, cfg: ModelConfig, max_len=None
                     c = _pad_full_cache(k, v, max_len)
             hh = hh + y
             hn = rmsnorm(hh, lp["ln2"])
-            if cfg.n_experts:
+            if "moe" not in lp:
+                m = lp["mlp"]
+                hh = hh + token_chunked(lambda t: swiglu(t, m["wg"], m["wu"], m["wd"]),
+                                        hn, chunk=max(1, TOKEN_CHUNK // s))
+            elif cfg.experts_held:
+                y2, idx, _ = moe.share_block(hn, lp["moe"], cfg)
+                hh = hh + y2
+                c.update(_expert_stats(cfg), route=idx.reshape(b, s, -1)[:, -1])
+            else:
                 y2, _ = moe.moe_block(hn, lp["moe"], cfg)
                 hh = hh + y2
-            else:
-                hh = hh + swiglu(hn, lp["mlp"]["wg"], lp["mlp"]["wu"], lp["mlp"]["wd"])
             return hh, c
 
-        h, layer_c = scan_or_loop(dense_fn, h, params["layers"], cfg.unroll_layers)
-        caches = {"layers": layer_c}
+        caches = {}
+        if "dense_layers" in params:
+            h, caches["dense_layers"] = scan_or_loop(dense_fn, h, params["dense_layers"],
+                                                     cfg.unroll_layers)
+        h, caches["layers"] = scan_or_loop(dense_fn, h, params["layers"], cfg.unroll_layers)
 
     h = rmsnorm(h, params["final_norm"])
     pos = jnp.full((b,), s, jnp.int32)
@@ -399,9 +416,29 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int):
     if cfg.family == "ssm":
         return {"layers": rep(ssm.ssd_init_cache(cfg, batch), cfg.n_layers)}
     if cfg.use_mla:
-        return {"layers": rep(attention.mla_init_cache(cfg, batch, max_len), cfg.n_layers)}
-    eff = max_len if cfg.sliding_window is None else min(max_len, cfg.sliding_window)
-    return {"layers": rep(attention.gqa_init_cache(cfg, batch, eff), cfg.n_layers)}
+        layer = attention.mla_init_cache(cfg, batch, max_len)
+    else:
+        eff = max_len if cfg.sliding_window is None else min(max_len, cfg.sliding_window)
+        layer = attention.gqa_init_cache(cfg, batch, eff)
+    if not cfg.first_k_dense and not cfg.experts_held:
+        return {"layers": rep(layer, cfg.n_layers)}
+    caches = {}
+    if cfg.first_k_dense:
+        caches["dense_layers"] = rep(layer, cfg.first_k_dense)
+    if cfg.experts_held:
+        layer = {**layer, **_expert_stats(cfg),
+                 "route": jnp.zeros((batch, cfg.top_k), jnp.int32)}
+    caches["layers"] = rep(layer, cfg.n_layers - cfg.first_k_dense)
+    return caches
+
+
+def _expert_stats(cfg: ModelConfig) -> dict:
+    """The expert share's per-layer counters, kept in the decode state:
+    token-choices routed to each held expert and rows each held expert
+    computed (padding included), summed over decode steps (prefill starts
+    them at 0)."""
+    z = jnp.zeros((cfg.experts_held,), jnp.int32)
+    return {"expert_tokens": z, "expert_rows": z}
 
 
 def _decode_dense_layer(h, lp, cache_l, cfg: ModelConfig, pos):
@@ -413,11 +450,16 @@ def _decode_dense_layer(h, lp, cache_l, cfg: ModelConfig, pos):
         a, new_cache = attention.gqa_decode(hn, lp["attn"], cfg, cache_l, pos, ring=ring)
     h = h + a
     hn = rmsnorm(h, lp["ln2"])
-    if cfg.n_experts:
+    if "moe" not in lp:
+        h = h + swiglu(hn, lp["mlp"]["wg"], lp["mlp"]["wu"], lp["mlp"]["wd"])
+    elif cfg.experts_held:
+        y, idx, counts = moe.share_block(hn, lp["moe"], cfg)
+        h = h + y
+        new_cache.update(route=idx, expert_tokens=cache_l["expert_tokens"] + counts,
+                         expert_rows=cache_l["expert_rows"] + idx.shape[0])
+    else:
         y, _ = moe.moe_block(hn, lp["moe"], cfg)
         h = h + y
-    else:
-        h = h + swiglu(hn, lp["mlp"]["wg"], lp["mlp"]["wu"], lp["mlp"]["wd"])
     return h, new_cache
 
 
@@ -437,6 +479,28 @@ def _decode_hybrid_sub(h, sp, cache_s, kind, cfg: ModelConfig, pos):
     h = h + y
     hn = rmsnorm(h, sp["ln2"])
     return h + swiglu(hn, sp["mlp"]["wg"], sp["mlp"]["wu"], sp["mlp"]["wd"]), new_cache
+
+
+def _decode_in_place(h, stacked, caches, layer, cfg: ModelConfig, pos):
+    """A decode step over one stacked layer group with its caches carried
+    through the scan and each layer's cache written back into its slot:
+    the donated stack is updated where it lies, where a scan that emits the
+    caches as outputs builds a second stack of them (one more cache of
+    temporaries).  The latent attention's caches take this path."""
+
+    def body(carry, xs):
+        hh, cc = carry
+        lp, i = xs
+        lc = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), cc)
+        hh, nc = layer(hh, lp, lc, cfg, pos)
+        cc = jax.tree.map(
+            lambda a, b: jax.lax.dynamic_update_index_in_dim(a, b.astype(a.dtype), i, 0),
+            cc, nc)
+        return (hh, cc), None
+
+    (h, caches), _ = scan_or_loop(body, (h, caches), (stacked, jnp.arange(_stack_len(stacked))),
+                                  cfg.unroll_layers)
+    return h, caches
 
 
 def lm_decode_hidden(
@@ -474,8 +538,16 @@ def lm_decode_hidden(
             hh, nc = layer(hh, lp, lc, cfg, pos)
             return hh, nc
 
-        h, new_layers = scan_or_loop(body, h, (params["layers"], caches["layers"]), cfg.unroll_layers)
-        new_caches = {"layers": new_layers}
+        new_caches = {}
+        for name in ("dense_layers", "layers"):
+            if name not in params:
+                continue
+            if cfg.use_mla:
+                h, new_caches[name] = _decode_in_place(h, params[name], caches[name], layer,
+                                                       cfg, pos)
+            else:
+                h, new_caches[name] = scan_or_loop(body, h, (params[name], caches[name]),
+                                                   cfg.unroll_layers)
 
     h = rmsnorm(h, params["final_norm"])
     return h, new_caches

@@ -136,6 +136,7 @@ def test_long_500k_skip_policy():
     assert runnable == {
         "deepseek-moe-16b": False,
         "deepseek-v2-236b": False,
+        "deepseek-v2-lite": False,
         "h2o-danube-1.8b": True,
         "deepseek-coder-33b": False,
         "mistral-nemo-12b": False,

@@ -10,6 +10,9 @@ imported or collected.  The persistent compile cache is off around these
 compiles (an entry compiled for a described chip cannot be read back).
 """
 
+import dataclasses
+import json
+import pathlib
 import re
 
 import jax
@@ -21,7 +24,9 @@ from repro import configs, engine
 from repro.configs import har_odl
 from repro.engine import stream
 from repro.kernels import oselm_update, xorshift_proj
+from repro.configs.base import ModelConfig
 from repro.models import model as model_lib
+from repro.models import transformer
 
 ARCH = "h2o-danube-1.8b"  # the serve path's full-width backbone
 S, N, N_IN, N_OUT = 32, 128, 2560, 6  # serve batch, head width, d_model
@@ -156,3 +161,55 @@ def test_compacted_learn_plan_updates_p_in_place(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= s * N * N * 4  # P is donated
     assert mem.temp_size_in_bytes < s * N * N * 4 / 2
+
+
+def _dsv2lite():
+    """DeepSeek-V2-Lite as the benchmark cuts it to one chip (its
+    configuration file's backbone): published widths, 8 of 64 experts
+    held, the dense layer and 10 MoE layers."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench" / "configs"
+            / "deepseek-v2-lite.json")
+    b = json.loads(path.read_text())["backbone"]
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    return ModelConfig(arch_id="deepseek-v2-lite",
+                       **{k: v for k, v in b.items() if k in fields})
+
+
+DSV2_B, DSV2_PROMPT, DSV2_MAX = 512, 128, 512  # the dsv2lite.decode512 cell
+V5E_PEAK_LIMIT = 14.5e9
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_dsv2lite_serve_step_fits_one_chip(one_chip, step):
+    """The expert-share decode (absorbed latent attention) and prefill at
+    DeepSeek-V2-Lite's published widths and the configuration's depth,
+    float32 weights, 512 streams, 512-slot latent cache: they compile, fit
+    under 14.5 GB, and the decode never expands the cache into per-head
+    keys or values ((streams, slots, heads, ...) tensors)."""
+    cfg = _dsv2lite()
+    params = jax.eval_shape(
+        lambda k: model_lib.layers.init_params(model_lib.build_schema(cfg), k),
+        jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((DSV2_B, DSV2_PROMPT), jnp.int32)
+
+    def prefill(p, t):
+        hidden, st = model_lib.prefill(p, t, cfg, max_len=DSV2_MAX)
+        return transformer.lm_logits(p, hidden[:, -1:], cfg), st
+
+    if step == "prefill":
+        lowered = jax.jit(prefill).lower(*_on(one_chip, (params, tokens)))
+    else:
+        _, state = jax.eval_shape(prefill, params, tokens)
+        tok = jax.ShapeDtypeStruct((DSV2_B, 1), jnp.int32)
+        lowered = jax.jit(lambda p, st, t: model_lib.decode_step(p, st, t, cfg),
+                          donate_argnums=(1,)).lower(*_on(one_chip, (params, state, tok)))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 6e9  # the weights really are f32
+    assert total < V5E_PEAK_LIMIT, mem
+    if step == "decode":
+        expanded = f"[{DSV2_B},{DSV2_MAX},{cfg.n_heads},"
+        assert expanded not in compiled.as_text()
+        assert mem.alias_size_in_bytes > 3e9  # the latent cache is updated in place
