@@ -100,12 +100,6 @@ class ModelConfig:
     # softmax over KV chunks, O(S * chunk) memory (hillclimb variant).
     attention_impl: str = "naive"
     attention_chunk: int = 1024
-    # Decode cache write: 'onehot' (per-stream positions, but rewrites the
-    # whole cache: O(S) HBM traffic per token) or 'dus' (dynamic-update-
-    # slice at pos[0]: O(1) traffic; requires synchronized stream positions
-    # — the common serving case).  §Perf H3.
-    cache_update: str = "onehot"
-
     # Dry-run cost extrapolation: execute layer stacks as a Python loop
     # instead of lax.scan (XLA cost_analysis counts a loop body ONCE, so the
     # roofline compiles unrolled 1- and 2-layer variants and extrapolates).

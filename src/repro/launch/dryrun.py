@@ -67,8 +67,6 @@ def _cfg_for_variant(cfg, variant: str):
             cfg = cfg.replace(attention_impl="chunked", attention_chunk=512)
         elif part == "ep":
             cfg = cfg.replace(moe_impl="ep")
-        elif part == "dus":
-            cfg = cfg.replace(cache_update="dus")
     return cfg
 
 

@@ -1,10 +1,11 @@
 """Attention variants: GQA (full / sliding-window), MLA, cross-attention.
 
 All functions are shape-polymorphic over (B, S) and share one KV-cache
-convention for decode:
+convention for decode: a stack of the layers' caches, of which a decode
+step of layer l writes each row's one new position (``write_rows``):
 
-  GQA cache:  {"k": (B, S_max, KV, hd), "v": (B, S_max, KV, hd), "pos": (B,)}
-  MLA cache:  {"ckv": (B, S_max, kv_lora), "k_rope": (B, S_max, rope_dim), "pos": (B,)}
+  GQA cache:  {"k": (L, B, S_max, KV * hd), "v": (L, B, S_max, KV * hd)}
+  MLA cache:  {"ckv": (L, B, S_max, kv_lora), "k_rope": (L, B, S_max, rope_dim)}
 
 MLA caches the *compressed* latent (DeepSeek-V2's serving advantage: 576
 floats/token vs 2 * KV * hd) — the property that makes deepseek-v2 the
@@ -223,10 +224,10 @@ def gqa_attention(
 
 
 def gqa_init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=layers.COMPUTE_DTYPE):
-    kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    width = cfg.n_kv_heads * cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((batch, max_len, kv, hd), dtype),
-        "v": jnp.zeros((batch, max_len, kv, hd), dtype),
+        "k": jnp.zeros((batch, max_len, width), dtype),
+        "v": jnp.zeros((batch, max_len, width), dtype),
     }
 
 
@@ -235,30 +236,32 @@ def gqa_decode(
     p: dict,
     cfg: ModelConfig,
     cache: dict,
+    layer,
     pos: jnp.ndarray,  # (B,) ABSOLUTE position (tokens already cached)
     window: Optional[int] = None,
     ring: bool = False,
 ) -> tuple[jnp.ndarray, dict]:
-    """One decode step.  ``ring=True`` treats the cache as a circular buffer
-    of the last ``cache_len`` tokens (sliding-window archs): K/V are written
-    at slot ``pos % cache_len`` but RoPE always uses absolute positions, and
-    each slot's absolute position is reconstructed for masking."""
+    """One decode step of layer ``layer`` over the stacked cache ({"k", "v"}:
+    (L, B, S_max, KV * hd)): each row's new K/V is written into its slot of
+    the stack (``write_rows``) and attention reads the layer back with it.
+    A position's heads lie in one row of KV*hd values: a (..., KV, hd)
+    stack with hd 80 is laid out on the TPU with the sequence dimension
+    minor, and a row write there makes the compiler relay the whole stack
+    out around the layer loop; rows of KV*hd keep it row-major.  ``ring=True`` treats
+    the cache as a circular buffer of the last ``cache_len`` tokens
+    (sliding-window archs): K/V are written at slot ``pos % cache_len`` but
+    RoPE always uses absolute positions, and each slot's absolute position
+    is reconstructed for masking.  Returns the output and the updated
+    stack."""
     b = x.shape[0]
-    cache_len = cache["k"].shape[1]
+    cache_len = cache["k"].shape[2]
+    kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     q, k_new, v_new = _qkv(x, p, cfg, pos[:, None])  # RoPE at absolute pos
     slot = pos % cache_len if ring else pos
-    if cfg.cache_update == "dus":
-        # O(1)-traffic write at the (synchronized) stream position.
-        k = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.astype(cache["k"].dtype), (0, slot[0], 0, 0)
-        )
-        v = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.astype(cache["v"].dtype), (0, slot[0], 0, 0)
-        )
-    else:
-        oh = jax.nn.one_hot(slot, cache_len, dtype=cache["k"].dtype)  # (B, S_max)
-        k = _scatter_cache(cache["k"], k_new, oh)
-        v = _scatter_cache(cache["v"], v_new, oh)
+    cache = {"k": write_rows(cache["k"], k_new.reshape(b, 1, kv * hd), layer, slot),
+             "v": write_rows(cache["v"], v_new.reshape(b, 1, kv * hd), layer, slot)}
+    k, v = (jax.lax.dynamic_index_in_dim(cache[n], layer, keepdims=False)
+            .reshape(b, cache_len, kv, hd) for n in ("k", "v"))
 
     j = jnp.arange(cache_len)[None]  # (1, S_max)
     if ring:
@@ -275,13 +278,17 @@ def gqa_decode(
     o = _grouped_attention(q, k.astype(x.dtype), v.astype(x.dtype), bias)
     o = o.reshape(b, 1, cfg.n_heads, cfg.resolved_head_dim).astype(x.dtype)
     out = jnp.einsum("bshe,hed->bsd", o, cast(p["wo"]))
-    return out, {"k": k, "v": v}
+    return out, cache
 
 
-def _scatter_cache(buf, new, oh):
-    """buf (B,S,KV,hd); new (B,1,KV,hd); oh (B,S) one-hot at write position."""
-    keep = (1.0 - oh)[:, :, None, None].astype(buf.dtype)
-    return buf * keep + oh[:, :, None, None].astype(buf.dtype) * new.astype(buf.dtype)
+def write_rows(stack, new, layer, slot):
+    """``stack`` (L, B, S_max, ...) with each row b's new entry ``new[b, 0]``
+    written at (layer, b, slot[b]): one scatter of B rows, in place where
+    the stack is donated or carried through a layer scan, for rows at equal
+    or different positions alike."""
+    rows = jnp.arange(stack.shape[1])
+    return stack.at[layer, rows, slot].set(new[:, 0].astype(stack.dtype),
+                                           indices_are_sorted=True, unique_indices=True)
 
 
 # ---------------------------------------------------------------------------
@@ -371,27 +378,22 @@ def mla_init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=layers.COMP
     }
 
 
-def mla_decode(x, p, cfg: ModelConfig, cache, pos):
-    """One decode step over the latent cache with the up-projections
-    absorbed: the query's nope part is taken through W_UK into the latent,
-    scores are read against the cached latent and roped key directly, the
-    values are summed in the latent and taken out through W_UV and wo.  The
-    cache is read once and never expanded to per-head keys or values."""
-    max_len = cache["ckv"].shape[1]
+def mla_decode(x, p, cfg: ModelConfig, cache, layer, pos):
+    """One decode step of layer ``layer`` over the stacked latent cache
+    ({"ckv", "k_rope"}: (L, B, S_max, ·)) with the up-projections absorbed:
+    each row's new latent and roped key are written into their slots of the
+    stack (``write_rows``), the query's nope part is taken through W_UK
+    into the latent, scores are read against the layer's cached latent and
+    roped key directly, the values are summed in the latent and taken out
+    through W_UV and wo.  The cache is read once and never expanded to
+    per-head keys or values.  Returns the output and the updated stack."""
+    max_len = cache["ckv"].shape[2]
     q_nope, q_rope = _mla_q(x, p, cfg, pos[:, None])
     ckv_new, k_rope_new = _mla_kv_latent(x, p, cfg, pos[:, None])
-
-    if cfg.cache_update == "dus":
-        ckv = jax.lax.dynamic_update_slice(
-            cache["ckv"], ckv_new.astype(cache["ckv"].dtype), (0, pos[0], 0)
-        )
-        k_rope = jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype), (0, pos[0], 0)
-        )
-    else:
-        oh = jax.nn.one_hot(pos, max_len, dtype=cache["ckv"].dtype)
-        ckv = _scatter_flat(cache["ckv"], ckv_new, oh)
-        k_rope = _scatter_flat(cache["k_rope"], k_rope_new, oh)
+    cache = {"ckv": write_rows(cache["ckv"], ckv_new, layer, pos),
+             "k_rope": write_rows(cache["k_rope"], k_rope_new, layer, pos)}
+    ckv, k_rope = (jax.lax.dynamic_index_in_dim(cache[n], layer, keepdims=False)
+                   for n in ("ckv", "k_rope"))
 
     w_uk, w_uv = jnp.split(cast(p["wkv_b"]), [cfg.qk_nope_dim], axis=-1)
     with jax.named_scope("mla.absorb"):
@@ -410,13 +412,7 @@ def mla_decode(x, p, cfg: ModelConfig, cache, pos):
     with jax.named_scope("mla.absorb"):
         o = jnp.einsum("bqhr,rhe->bqhe", o_lat.astype(x.dtype), w_uv)
     out = jnp.einsum("bshe,hed->bsd", o.astype(x.dtype), cast(p["wo"]))
-    return out, {"ckv": ckv, "k_rope": k_rope}
-
-
-def _scatter_flat(buf, new, oh):
-    """buf (B,S,D); new (B,1,D); oh (B,S)."""
-    keep = 1.0 - oh
-    return buf * keep[:, :, None] + oh[:, :, None] * new.astype(buf.dtype)
+    return out, cache
 
 
 # ---------------------------------------------------------------------------

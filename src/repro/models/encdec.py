@@ -26,7 +26,7 @@ from repro.models.layers import (
     sinusoidal_embedding,
     stack_schema,
 )
-from repro.models.transformer import scan_or_loop
+from repro.models.transformer import decode_stack, scan_or_loop
 
 
 def _ln(d):
@@ -155,20 +155,18 @@ def decode_step(params: dict, token: jnp.ndarray, caches: dict, pos: jnp.ndarray
     """One decoder token; cross-attn reads precomputed K/V."""
     h = cast(params["embed"])[token] + cast(params["dec_pos"])[pos][:, None]
 
-    def layer(hh, xs):
-        lp, sc, ck, cv = xs
+    def layer(hh, lp, sc, i, cross):
         hn = layernorm(hh, lp["ln1"]["w"], lp["ln1"]["b"])
-        a, new_sc = attention.gqa_decode(hn, lp["self"], cfg, sc, pos)
+        a, sc = attention.gqa_decode(hn, lp["self"], cfg, sc, i, pos)
         hh = hh + a
         hn = layernorm(hh, lp["ln2"]["w"], lp["ln2"]["b"])
-        hh = hh + _cross_from_cache(hn, lp["cross"], ck, cv)
+        hh = hh + _cross_from_cache(hn, lp["cross"], *cross)
         hn = layernorm(hh, lp["ln3"]["w"], lp["ln3"]["b"])
-        return hh + gelu_mlp(hn, lp["mlp"]["wi"], lp["mlp"]["wo"]), new_sc
+        return hh + gelu_mlp(hn, lp["mlp"]["wi"], lp["mlp"]["wo"]), sc, None
 
-    h, new_self = scan_or_loop(
-        layer, h,
-        (params["dec_layers"], caches["self"], caches["cross_k"], caches["cross_v"]),
-        cfg.unroll_layers,
+    h, new_self, _ = decode_stack(
+        h, params["dec_layers"], caches["self"], (caches["cross_k"], caches["cross_v"]),
+        layer, cfg.unroll_layers,
     )
     h = layernorm(h, params["dec_norm"]["w"], params["dec_norm"]["b"])
     return h, {"self": new_self, "cross_k": caches["cross_k"], "cross_v": caches["cross_v"]}

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Optional
 
 import jax
@@ -53,13 +54,15 @@ def _iter_leaves(schema, path=()):
 
 
 def init_params(schema, key, dtype=jnp.float32):
-    """Materialize a schema into arrays (per-leaf fold_in keys)."""
+    """Materialize a schema into arrays (per-leaf fold_in keys, from a
+    checksum of the leaf's path, so a key gives the same arrays in every
+    process)."""
     out = {}
     for path, leaf in _iter_leaves(schema):
         sub = out
         for k in path[:-1]:
             sub = sub.setdefault(k, {})
-        lk = jax.random.fold_in(key, abs(hash("/".join(map(str, path)))) % (2**31))
+        lk = jax.random.fold_in(key, zlib.crc32("/".join(map(str, path)).encode()) % (2**31))
         sub[path[-1]] = leaf.initializer(lk, dtype)
     return out
 

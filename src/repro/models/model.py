@@ -368,8 +368,8 @@ def cache_axes(path: tuple, leaf) -> tuple:
     name = path[-1].strip("'[]")
     nd = leaf.ndim
     lead: tuple = ("layers",)  # stacked layer/group dim
-    if name in ("k", "v"):  # (L, B, S, KV, hd)
-        return lead + ("batch", "seq_kv", "kv_heads", None)[: nd - 1]
+    if name in ("k", "v"):  # (L, B, S, KV * hd)
+        return lead + ("batch", "seq_kv", "kv_heads")[: nd - 1]
     if name in ("ckv", "k_rope"):  # (L, B, S, R)
         return lead + ("batch", "seq_kv", None)[: nd - 1]
     if name == "state":  # (L, B, H, P, N)

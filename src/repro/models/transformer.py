@@ -257,22 +257,25 @@ def lm_logits(params: dict, hidden: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarra
 
 
 def _pad_full_cache(k, v, max_len):
-    """Full-attention cache: (B,S,KV,hd) K/V padded to max_len slots."""
+    """Full-attention cache: (B,S,KV,hd) K/V as rows of KV*hd, padded to
+    max_len slots."""
     b, s, kv, hd = k.shape
+    k, v = k.reshape(b, s, kv * hd), v.reshape(b, s, kv * hd)
     if s == max_len:
         return {"k": k, "v": v}
-    kp = jnp.zeros((b, max_len, kv, hd), k.dtype).at[:, :s].set(k)
-    vp = jnp.zeros((b, max_len, kv, hd), v.dtype).at[:, :s].set(v)
+    kp = jnp.zeros((b, max_len, kv * hd), k.dtype).at[:, :s].set(k)
+    vp = jnp.zeros((b, max_len, kv * hd), v.dtype).at[:, :s].set(v)
     return {"k": kp, "v": vp}
 
 
 def _ring_cache(k, v, win, s_total):
-    """Sliding-window ring: last win tokens at slots (abs_pos % win)."""
+    """Sliding-window ring: last win tokens at slots (abs_pos % win), as
+    rows of KV*hd."""
     b, s, kv, hd = k.shape
     take = min(s, win)
     idx = (jnp.arange(s_total - take, s_total)) % win
-    kr = jnp.zeros((b, win, kv, hd), k.dtype).at[:, idx].set(k[:, -take:])
-    vr = jnp.zeros((b, win, kv, hd), v.dtype).at[:, idx].set(v[:, -take:])
+    kr = jnp.zeros((b, win, kv * hd), k.dtype).at[:, idx].set(k[:, -take:].reshape(b, take, -1))
+    vr = jnp.zeros((b, win, kv * hd), v.dtype).at[:, idx].set(v[:, -take:].reshape(b, take, -1))
     return {"k": kr, "v": vr}
 
 
@@ -441,13 +444,17 @@ def _expert_stats(cfg: ModelConfig) -> dict:
     return {"expert_tokens": z, "expert_rows": z}
 
 
-def _decode_dense_layer(h, lp, cache_l, cfg: ModelConfig, pos):
+def _decode_dense_layer(h, lp, cc, i, lc, cfg: ModelConfig, pos):
+    """Layer ``i`` of a dense or MoE stack over the carried caches ``cc``
+    (``lc`` is None): the attention writes its rows into the stack, the
+    expert share's counters and route are updated at ``i``."""
     hn = rmsnorm(h, lp["ln1"])
     if cfg.use_mla:
-        a, new_cache = attention.mla_decode(hn, lp["attn"], cfg, cache_l, pos)
+        a, kv = attention.mla_decode(hn, lp["attn"], cfg, cc, i, pos)
     else:
         ring = cfg.sliding_window is not None
-        a, new_cache = attention.gqa_decode(hn, lp["attn"], cfg, cache_l, pos, ring=ring)
+        a, kv = attention.gqa_decode(hn, lp["attn"], cfg, cc, i, pos, ring=ring)
+    cc = {**cc, **kv}
     h = h + a
     hn = rmsnorm(h, lp["ln2"])
     if "moe" not in lp:
@@ -455,52 +462,63 @@ def _decode_dense_layer(h, lp, cache_l, cfg: ModelConfig, pos):
     elif cfg.experts_held:
         y, idx, counts = moe.share_block(hn, lp["moe"], cfg)
         h = h + y
-        new_cache.update(route=idx, expert_tokens=cache_l["expert_tokens"] + counts,
-                         expert_rows=cache_l["expert_rows"] + idx.shape[0])
+        cc.update(route=cc["route"].at[i].set(idx),
+                  expert_tokens=cc["expert_tokens"].at[i].add(counts),
+                  expert_rows=cc["expert_rows"].at[i].add(idx.shape[0]))
     else:
         y, _ = moe.moe_block(hn, lp["moe"], cfg)
         h = h + y
-    return h, new_cache
+    return h, cc, None
 
 
-def _decode_ssm_layer(h, lp, cache_l, cfg: ModelConfig, pos):
-    del pos
-    y, new_cache = ssm.ssd_decode(rmsnorm(h, lp["norm"]), lp["ssd"], cfg, cache_l)
-    return h + y, new_cache
+def _decode_ssm_layer(h, lp, cc, i, lc, cfg: ModelConfig, pos):
+    """Layer ``i`` of an SSM stack: its state ``lc`` is updated whole."""
+    del i, pos
+    y, lc = ssm.ssd_decode(rmsnorm(h, lp["norm"]), lp["ssd"], cfg, lc)
+    return h + y, cc, lc
 
 
-def _decode_hybrid_sub(h, sp, cache_s, kind, cfg: ModelConfig, pos):
-    hn = rmsnorm(h, sp["ln1"])
-    if kind == "rec":
-        y, new_cache = rglru.rglru_decode(hn, sp["rec"], cfg, cache_s)
-    else:
-        # Local attention over a ring buffer of the last `window` tokens.
-        y, new_cache = attention.gqa_decode(hn, sp["attn"], cfg, cache_s, pos, ring=True)
-    h = h + y
-    hn = rmsnorm(h, sp["ln2"])
-    return h + swiglu(hn, sp["mlp"]["wg"], sp["mlp"]["wu"], sp["mlp"]["wd"]), new_cache
+def _decode_hybrid_group(h, gp, cc, i, rec, cfg: ModelConfig, pos):
+    """Pattern group ``i`` of a hybrid stack: local attention writes its
+    rows into the carried ring caches ``cc``, the recurrent blocks update
+    their states ``rec`` whole."""
+    cc, rec = dict(cc), dict(rec)
+    for j, kind in enumerate(cfg.hybrid_pattern):
+        key = f"b{j}_{kind}"
+        if key not in gp:
+            continue
+        sp = gp[key]
+        hn = rmsnorm(h, sp["ln1"])
+        if kind == "rec":
+            y, rec[key] = rglru.rglru_decode(hn, sp["rec"], cfg, rec[key])
+        else:
+            # Local attention over a ring buffer of the last `window` tokens.
+            y, cc[key] = attention.gqa_decode(hn, sp["attn"], cfg, cc[key], i, pos, ring=True)
+        h = h + y
+        hn = rmsnorm(h, sp["ln2"])
+        h = h + swiglu(hn, sp["mlp"]["wg"], sp["mlp"]["wu"], sp["mlp"]["wd"])
+    return h, cc, rec
 
 
-def _decode_in_place(h, stacked, caches, layer, cfg: ModelConfig, pos):
-    """A decode step over one stacked layer group with its caches carried
-    through the scan and each layer's cache written back into its slot:
-    the donated stack is updated where it lies, where a scan that emits the
-    caches as outputs builds a second stack of them (one more cache of
-    temporaries).  The latent attention's caches take this path."""
+def decode_stack(h, stacked, carried, per_layer, layer, unroll: bool):
+    """A decode step over one stacked layer group, ``layer(h, lp, carried,
+    i, lc) -> (h, carried, lc')`` at each layer ``i``.  ``carried`` (the
+    attention caches and their counters) rides in the scan's carry, so each
+    layer writes its rows into the donated stack where it lies; a scan that
+    emitted the caches as outputs would build a second stack of them.
+    ``per_layer`` (recurrent states, whole-state updates; read-only inputs)
+    goes in as the scan's inputs, and the ``lc'`` come out as its outputs
+    (None for none).  Returns (h, carried, outputs)."""
 
     def body(carry, xs):
         hh, cc = carry
-        lp, i = xs
-        lc = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), cc)
-        hh, nc = layer(hh, lp, lc, cfg, pos)
-        cc = jax.tree.map(
-            lambda a, b: jax.lax.dynamic_update_index_in_dim(a, b.astype(a.dtype), i, 0),
-            cc, nc)
-        return (hh, cc), None
+        lp, lc, i = xs
+        hh, cc, lc = layer(hh, lp, cc, i, lc)
+        return (hh, cc), lc
 
-    (h, caches), _ = scan_or_loop(body, (h, caches), (stacked, jnp.arange(_stack_len(stacked))),
-                                  cfg.unroll_layers)
-    return h, caches
+    xs = (stacked, per_layer, jnp.arange(_stack_len(stacked)))
+    (h, carried), ys = scan_or_loop(body, (h, carried), xs, unroll)
+    return h, carried, ys
 
 
 def lm_decode_hidden(
@@ -512,42 +530,28 @@ def lm_decode_hidden(
 ) -> tuple[jnp.ndarray, dict]:
     """One decode step -> final hidden (B, 1, d) + updated caches."""
     h = cast(params["embed"])[token]
-
+    new_caches = {}
     if cfg.hybrid_pattern:
-        pat = cfg.hybrid_pattern
-
-        def group_fn(hh, xs):
-            gp, gc = xs
-            new_gc = {}
-            for i, kind in enumerate(pat):
-                key = f"b{i}_{kind}"
-                if key in gp:
-                    hh, new_gc[key] = _decode_hybrid_sub(hh, gp[key], gc[key], kind, cfg, pos)
-            return hh, new_gc
-
-        h, new_groups = scan_or_loop(group_fn, h, (params["groups"], caches["groups"]), cfg.unroll_layers)
-        new_caches = {"groups": new_groups}
-        if "tail" in params:
-            h, new_tail = scan_or_loop(group_fn, h, (params["tail"], caches["tail"]), cfg.unroll_layers)
-            new_caches["tail"] = new_tail
-    else:
-        layer = _decode_ssm_layer if cfg.family == "ssm" else _decode_dense_layer
-
-        def body(hh, xs):
-            lp, lc = xs
-            hh, nc = layer(hh, lp, lc, cfg, pos)
-            return hh, nc
-
-        new_caches = {}
-        for name in ("dense_layers", "layers"):
+        rec_keys = {f"b{j}_rec" for j, kind in enumerate(cfg.hybrid_pattern) if kind == "rec"}
+        layer = functools.partial(_decode_hybrid_group, cfg=cfg, pos=pos)
+        for name in ("groups", "tail"):
             if name not in params:
                 continue
-            if cfg.use_mla:
-                h, new_caches[name] = _decode_in_place(h, params[name], caches[name], layer,
-                                                       cfg, pos)
-            else:
-                h, new_caches[name] = scan_or_loop(body, h, (params[name], caches[name]),
-                                                   cfg.unroll_layers)
+            c = caches[name]
+            h, attn, rec = decode_stack(
+                h, params[name], {k: v for k, v in c.items() if k not in rec_keys},
+                {k: v for k, v in c.items() if k in rec_keys}, layer, cfg.unroll_layers)
+            new_caches[name] = {**attn, **rec}
+    elif cfg.family == "ssm":
+        layer = functools.partial(_decode_ssm_layer, cfg=cfg, pos=pos)
+        h, _, new_caches["layers"] = decode_stack(h, params["layers"], {}, caches["layers"],
+                                                  layer, cfg.unroll_layers)
+    else:
+        layer = functools.partial(_decode_dense_layer, cfg=cfg, pos=pos)
+        for name in ("dense_layers", "layers"):
+            if name in params:
+                h, new_caches[name], _ = decode_stack(h, params[name], caches[name], None,
+                                                      layer, cfg.unroll_layers)
 
     h = rmsnorm(h, params["final_norm"])
     return h, new_caches

@@ -197,10 +197,10 @@ def test_absorbed_decode_equals_the_expanded_latent_attention(f32_program, q_lor
     x = jax.random.normal(jax.random.PRNGKey(6), (b, s, cfg.d_model), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     want = attention.mla_attention(x, p, cfg, pos)
-    cache = attention.mla_init_cache(cfg, b, s, jnp.float32)
+    cache = jax.tree.map(lambda a: a[None], attention.mla_init_cache(cfg, b, s, jnp.float32))
     got = []
     for t in range(s):
-        o, cache = attention.mla_decode(x[:, t: t + 1], p, cfg, cache, jnp.full((b,), t))
+        o, cache = attention.mla_decode(x[:, t: t + 1], p, cfg, cache, 0, jnp.full((b,), t))
         got.append(o)
     np.testing.assert_allclose(np.concatenate(got, 1), np.asarray(want), rtol=2e-4, atol=2e-5)
 

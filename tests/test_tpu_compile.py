@@ -213,3 +213,100 @@ def test_dsv2lite_serve_step_fits_one_chip(one_chip, step):
         expanded = f"[{DSV2_B},{DSV2_MAX},{cfg.n_heads},"
         assert expanded not in compiled.as_text()
         assert mem.alias_size_in_bytes > 3e9  # the latent cache is updated in place
+
+
+def _hlo(text):
+    """The compiled program as {computation: {instruction: fields}}: the
+    output's dimensions (one tuple per array), whether it lies in device
+    memory (``hbm``: no ``S(n)`` memory space in its layout), the opcode,
+    the operands and the computations it calls, and whether it is its
+    computation's root."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line.rstrip())
+            cur = comps.setdefault(m.group(1), {}) if m else None
+            continue
+        m = re.match(r"\s*(ROOT )?%([\w.\-]+) = (.*)$", line)
+        if cur is None or not m:
+            continue
+        rest = m.group(3)
+        if rest.startswith("("):  # a tuple: up to its closing parenthesis
+            depth = 0
+            for n, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            shape, rest = rest[:n + 1], rest[n + 2:]
+        else:
+            shape, _, rest = rest.partition(" ")
+        op, _, args = rest.partition("(")
+        depth = 1
+        for end, ch in enumerate(args):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        cur[m.group(2)] = dict(
+            dims=[tuple(int(d) for d in g.split(",") if d)
+                  for g in re.findall(r"\w+\[([\d,]*)\]", shape)],
+            hbm="S(" not in shape, op=op, root=bool(m.group(1)),
+            operands=re.findall(r"%([\w.\-]+)", args[:end]),
+            calls=re.findall(r"(?:calls|body)=%([\w.\-]+)", args[end:]))
+    return comps
+
+
+def _row_write(comps, scope, ins, stacks, layers):
+    """A whole stack written in place by a scatter or dynamic-update-slice
+    (alone, or as a fusion's root) whose update is rows, not a layer."""
+    if ins["dims"] != [ins["dims"][0]] or ins["dims"][0] not in stacks:
+        return False
+    if ins["op"] == "fusion":
+        scope = comps[ins["calls"][0]]
+        ins = next(i for i in scope.values() if i["root"])
+    if ins["op"] not in ("scatter", "dynamic-update-slice"):
+        return False
+    update = scope[ins["operands"][2 if ins["op"] == "scatter" else 1]]
+    return not set(update["dims"]) & (stacks | layers)
+
+
+@pytest.mark.parametrize("cell", ["danube.decode32", "dsv2lite.decode512"])
+def test_decode_step_writes_rows_into_the_carried_stack(one_chip, cell):
+    """The cells' decode steps at their shapes, the state donated as the
+    benchmark donates it: in the layer scan's body no op puts a whole
+    layer's cache or the whole stack into device memory but the in-place
+    row write into the carried stack (so no slice-write-put-back round trip
+    and no one-hot rewrite; a layer staged in on-chip memory for attention
+    to read is the read itself), no carried stack is copied anywhere, and
+    the caches are updated where they lie."""
+    if cell == "danube.decode32":
+        cfg, b, prompt, max_len = configs.get_config(ARCH, "full"), S, 128, 512
+    else:
+        cfg, b, prompt, max_len = _dsv2lite(), DSV2_B, DSV2_PROMPT, DSV2_MAX
+    params = jax.eval_shape(
+        lambda k: model_lib.layers.init_params(model_lib.build_schema(cfg), k),
+        jax.random.PRNGKey(0))
+    _, state = jax.eval_shape(
+        lambda p, t: model_lib.prefill(p, t, cfg, max_len=max_len),
+        params, jax.ShapeDtypeStruct((b, prompt), jnp.int32))
+    tok = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+    compiled = jax.jit(lambda p, st, t: model_lib.decode_step(p, st, t, cfg),
+                       donate_argnums=(1,)).lower(*_on(one_chip, (params, state, tok))).compile()
+    caches = [a for a in jax.tree.leaves(state.caches) if a.ndim >= 4]
+    stacks = {a.shape for a in caches}
+    layers = {a.shape[1:] for a in caches} | {(1,) + a.shape[1:] for a in caches}
+    comps = _hlo(compiled.as_text())
+    bodies = [c for ops in comps.values() for i in ops.values() if i["op"] == "while"
+              for c in i["calls"] if any(d in stacks for d in i["dims"])]
+    assert bodies  # the layer scan carries the stack
+    for body in bodies:
+        for name, ins in comps[body].items():
+            if ins["op"] in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+                continue
+            if ins["hbm"] and any(d in stacks | layers for d in ins["dims"]):
+                assert _row_write(comps, comps[body], ins, stacks, layers), (body, name, ins)
+    carried = {a.shape for a in caches if a.shape[0] > 1}
+    copies = [name for ops in comps.values() for name, i in ops.items()
+              if i["op"] in ("copy", "copy-start") and any(d in carried for d in i["dims"])]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in caches)
