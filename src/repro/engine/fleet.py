@@ -78,13 +78,6 @@ def slice_streams(state: EngineState, lo: int, hi: int) -> EngineState:
     return jax.tree.map(lambda a: a[lo:hi], state)
 
 
-def remove_streams(state: EngineState, lo: int, hi: int) -> EngineState:
-    """Drop the ``[lo:hi]`` stream window (evict a member from a cohort)."""
-    return jax.tree.map(
-        lambda a: jnp.concatenate([a[:lo], a[hi:]], axis=0), state
-    )
-
-
 @functools.lru_cache(maxsize=RUNNER_CACHE_SIZE)
 def _patch_learn_runner(cfg: EngineConfig, lo: int, hi: int, donate: bool):
     """Learn on one member's ``[lo:hi]`` row window of a stacked cohort

@@ -10,23 +10,24 @@ Tenants with the same ``(cfg, mode, donate)`` therefore share one compiled
 executable: adding a tenant with a config already being served costs no
 compile and no extra executable memory.
 
-Cohort fusion (``fuse=True``, the default) goes one step further: tenants
-that also share a stream width are packed into *cohorts*
-(``engine/cohort.py``) whose ``EngineState`` pytrees stack along the
-leading stream axis, so one fused stacked dispatch per tick advances the
-whole cohort instead of one dispatch per tenant — eliminating the
-tick-switch cache penalty entirely at high tenant counts.  Each tenant
-keeps its own ring / teacher / backpressure / stats / tick cursor, and
-per-tenant results stay bit-for-bit identical to the unfused run (locked
-by ``tests/test_cohort.py``); ``fuse=False`` restores the one-dispatch-
-per-tenant scheduler.
+Every tenant ticks inside a scheduling *unit*: a group of the one tick
+engine (``engine/cohort.py``).  Cohort fusion (``fuse=True``, the default)
+packs tenants that also share a stream width into one unit of several
+whose ``EngineState`` pytrees stack along the leading stream axis, so one
+stacked dispatch per tick advances the whole cohort instead of one
+dispatch per tenant — eliminating the tick-switch cache penalty entirely
+at high tenant counts.  ``fuse=False`` keeps every tenant in a unit of its
+own.  Each tenant keeps its own ring / teacher / backpressure / stats /
+tick cursor, and per-tenant results are bit-for-bit identical either way
+(locked by ``tests/test_cohort.py``).  A tenant whose tick source runs out
+leaves its cohort for a unit of its own and drains there.
 
 Scheduling (``sched``):
 
 * ``"rr"`` (default) — round-robin with a ``quantum``-tick time slice:
-  each tenant's ``StreamSession`` advances by up to ``quantum``
-  plan/ask/poll/learn cycles before the scheduler moves on (switching
-  every tick would evict the tenant's state from cache on every switch).
+  each unit advances by up to ``quantum`` plan/ask/poll/learn cycles
+  before the scheduler moves on (switching every tick would evict the
+  tenant's state from cache on every switch).
 * ``"drr"`` — deficit round robin in *stream-step* (cost) units: every
   round each live tenant's deficit grows by the same credit
   (``quantum × min S``) and one tick debits that tenant's own S, so a
@@ -91,9 +92,6 @@ import os
 import time
 from typing import Iterable, NamedTuple, Optional
 
-import numpy as np
-
-from repro.engine import cohort as cohort_mod
 from repro.engine import snapshot as snapshot_mod
 from repro.engine import stream
 from repro.engine.types import EngineConfig, EngineState, FleetStepOutput
@@ -312,58 +310,30 @@ class _Slot:
         # layers add their own labels at scrape time).
         self.session.telemetry_labels = {"tenant": tenant.name}
         # Tick cost for the deficit scheduler = this tenant's stream count.
-        self.s = int(np.shape(np.asarray(self.session.state.elm.count))[0])
-        self.deficit = 0.0
-        self.last_ticks = 0  # real ticks advanced in the last step() call
-        self.unit: Optional["_CohortUnit"] = None  # set while fused
+        self.s = self.session.width
+        self.unit: Optional["_Unit"] = None  # the unit this tenant ticks in
         self.snapshots_taken = 0
         self._last_snap_t = self.session.t
-        self.draining = False
+        # Source exhausted; a session restored from a snapshot taken after
+        # its stream ended has nothing left to plan, only the drain.
+        self.draining = self.session.started() and self.session._p is None
         self._drain_ticks = 0  # cumulative, capped at stream.MAX_DRAIN_TICKS
         self.result: Optional[TenantResult] = None
 
-    def step(self, drain: bool, n_ticks: int) -> bool:
-        """Advance this tenant by up to ``n_ticks`` scheduler events (or
-        one bounded drain slice once its ticks are exhausted).  Returns
-        True while the tenant still wants scheduling."""
-        sess = self.session
-        self.last_ticks = 0
-        if not self.draining:
-            for _ in range(n_ticks):
-                if not sess.started():
-                    x0 = self.pull()
-                    if x0 is None:  # empty tick source: nothing to run
-                        self.draining = True
-                        break
-                    sess.start(x0)
-                    continue
-                if sess._p is None:
-                    # Session restored from a snapshot taken after its
-                    # stream ended: nothing left to plan, only the drain.
-                    self.draining = True
-                    break
-                nxt = self.pull()
-                sess.advance(nxt)
-                self.last_ticks += 1
-                if nxt is None:
-                    self.draining = True
-                    break
-            self.maybe_snapshot()
-            if not self.draining:
+    def drain_slice(self, drain: bool) -> bool:
+        """One bounded slice of this exhausted tenant's drain, so other
+        tenants keep ticking while it waits out its teacher; settles the
+        tenant once done (at once when ``drain`` is off).  The cumulative
+        cap keeps a broken always-in-flight teacher from pinning the
+        scheduler forever (same bound a solo run's drain has).  Returns
+        True while it still wants scheduling."""
+        if drain:
+            self._drain_ticks += self.DRAIN_TICKS_PER_SLICE
+            if self._drain_ticks <= stream.MAX_DRAIN_TICKS and self.session.drain_replies(
+                max_ticks=self.DRAIN_TICKS_PER_SLICE,
+                idle_sleep_s=self.DRAIN_IDLE_SLEEP_S,
+            ):
                 return True
-            if not drain:
-                self._finish()
-                return False
-        # Draining: one bounded slice per round, so other tenants keep
-        # ticking while this one waits out its teacher.  The cumulative cap
-        # keeps a broken always-in-flight teacher from pinning the
-        # scheduler forever (same bound a solo run's drain has).
-        self._drain_ticks += self.DRAIN_TICKS_PER_SLICE
-        if self._drain_ticks <= stream.MAX_DRAIN_TICKS and sess.drain_replies(
-            max_ticks=self.DRAIN_TICKS_PER_SLICE,
-            idle_sleep_s=self.DRAIN_IDLE_SLEEP_S,
-        ):
-            return True
         self._finish()
         return False
 
@@ -398,17 +368,16 @@ class _Slot:
         )
         if not (due or force) or not self.session.started():
             return False
-        if self.unit is not None:
-            # Fused member: its session.state is stale while the cohort
-            # holds the authoritative stacked rows — write them back first.
-            self.unit.cohort.refresh(self.session)
+        # A fused member's session.state is stale while the cohort holds
+        # the authoritative stacked rows — write them back first.
+        self.unit.cohort.refresh(self.session)
         self.manager.save_async(self.session.t, self.session.snapshot())
         self._last_snap_t = self.session.t
         self.snapshots_taken += 1
         return True
 
     def _finish(self) -> None:
-        # Any draining already happened incrementally in step().
+        # Any draining already happened incrementally in drain_slice().
         if self.manager is not None:
             self.manager.wait()  # never finish with a snapshot mid-write
         state, outs, stats = self.session.finish(drain=False)
@@ -417,76 +386,74 @@ class _Slot:
         )
 
 
-class _CohortUnit:
-    """Scheduler-side unit driving one fused cohort of slots.
+class _Unit:
+    """Scheduler-side unit: the slots that tick together in one group of
+    the tick engine (``engine/cohort.py``) — one slot, or a fused cohort.
 
-    Takes the place of its member slots in the scheduler's live list: one
-    ``step`` advances the whole cohort in lockstep with fused dispatches
-    (``engine/cohort.py``).  ``s`` — the DRR tick cost — is the shared
-    member width, so each fused member receives exactly the credit/debit
-    schedule its solo slot would (cohorts only form between same-width
-    tenants); the fused tick just does all members' device work at once.
+    ``s`` — the DRR tick cost — is the members' shared width, so each
+    fused member receives exactly the credit/debit schedule it would in a
+    unit of its own (cohorts only form between same-width tenants); the
+    group's tick just does all members' device work at once.
     """
 
-    def __init__(self, slots: list[_Slot]):
-        self.slots = list(slots)
-        self.cohort = cohort_mod.CohortSession([s.session for s in slots])
-        self.s = slots[0].s
-        self.deficit = 0.0
-        self.last_ticks = 0
-        self.draining = False  # members drain solo, after release
-
-    def attach(self, slot: _Slot) -> None:
-        self.cohort.attach(slot.session)
-        self.slots.append(slot)
+    def __init__(self, slot: _Slot):
+        self.slots = [slot]
         slot.unit = self
+        self.cohort = slot.session.group()
+        self.s = slot.s
+        self.deficit = 0.0
+        self.last_ticks = 0  # real ticks advanced in the last step() call
 
-    def release(self, slot: _Slot) -> list[_Slot]:
-        """Detach one member (live migration out).  Returns slots freed as
-        a side effect: when one member remains the cohort dissolves and
-        that member continues solo."""
-        self.cohort.detach(slot.session)
+    @property
+    def draining(self) -> bool:
+        # Only a unit of one holds an exhausted tenant.
+        return self.slots[0].draining
+
+    def fuse_key(self):
+        """``(cfg, mode, donate, S)`` of a unit that can fuse, else None."""
+        if self.draining:
+            return None
+        sess = self.slots[0].session
+        return (sess.cfg, sess.mode, sess._donate, self.s)
+
+    def attach(self, slots: list[_Slot]) -> None:
+        self.cohort.attach(*(slot.session for slot in slots))
+        self.slots += slots
+        for slot in slots:
+            slot.unit = self
+
+    def release(self, slot: _Slot) -> None:
+        """Take one member out (live migration): it leaves the group for
+        one of its own."""
+        if len(self.slots) > 1:
+            self.cohort.detach(slot.session)
         self.slots.remove(slot)
         slot.unit = None
-        freed = []
-        if len(self.slots) == 1:
-            last = self.slots.pop()
-            self.cohort.detach(last.session)
-            last.unit = None
-            freed.append(last)
-        return freed
 
-    def step(self, drain: bool, n_ticks: int) -> tuple[bool, list[_Slot]]:
-        """Advance the cohort by up to ``n_ticks`` fused ticks.  Returns
-        ``(live, released)`` — live False once the cohort dissolved;
-        released slots (exhausted members, or the last member of a
-        dissolved cohort) re-enter the scheduler as independent slots."""
-        del drain  # released members drain through their solo slot path
+    def step(self, drain: bool, n_ticks: int) -> list["_Unit"]:
+        """Advance by up to ``n_ticks`` ticks of the group, or one drain
+        slice once its tenant's source ran out.  Returns the units live
+        after it: this one while it wants scheduling, and a unit of its own
+        for each member whose source ran out (which gets its first drain
+        slice in this same call, as this unit's own tenant does)."""
         self.last_ticks = 0
-        released: list[_Slot] = []
-        for _ in range(n_ticks):
-            if len(self.slots) < 2:
-                break
-            nxts = [s.pull() for s in self.slots]
-            detached, advanced = self.cohort.tick(nxts)
-            if advanced:
-                self.last_ticks += 1
-            for sess in detached:
-                slot = next(s for s in self.slots if s.session is sess)
-                self.slots.remove(slot)
-                slot.unit = None
-                slot.draining = True
-                slot.maybe_snapshot()
-                released.append(slot)
-        for slot in self.slots:
-            slot.maybe_snapshot()
-        if len(self.slots) == 1:
-            # A cohort of one is pure overhead: dissolve, continue solo.
-            last = self.slots.pop()
-            self.cohort.detach(last.session)
-            last.unit = None
-            released.append(last)
-        return bool(self.slots), released
+        units = [self]
+        if not self.draining:
+            for _ in range(n_ticks):
+                left, advanced = self.cohort.tick([s.pull() for s in self.slots])
+                self.last_ticks += advanced
+                for sess in left:
+                    slot = next(s for s in self.slots if s.session is sess)
+                    slot.draining = True
+                    if sess not in self.cohort.members:
+                        self.slots.remove(slot)
+                        units.append(_Unit(slot))
+                if self.draining:
+                    break
+            for u in units:
+                for slot in u.slots:
+                    slot.maybe_snapshot()
+        return [u for u in units if not u.draining or u.slots[0].drain_slice(drain)]
 
 
 DEFAULT_QUANTUM = 8
@@ -531,7 +498,6 @@ class Multiplexer:
         self.quantum = quantum
         self.sched = sched
         self.fuse = fuse
-        self._cohorts: dict = {}  # fuse key -> live _CohortUnit
         self.snapshot_dir = snapshot_dir
         self.snapshot_every = snapshot_every
         # Cadence saves ship only changed leaves, with a full snapshot every
@@ -541,8 +507,7 @@ class Multiplexer:
         self._pending = pending
         self.agg = MultiplexStats(n_tenants=len(tenants))
         self._slots: list[_Slot] = []
-        # Scheduling units: solo _Slots and fused _CohortUnits (fuse=True).
-        self._live: list = []
+        self._live: list[_Unit] = []  # scheduling units, in round order
         self._t0: Optional[float] = None
         for t in tenants:
             self.admit(t, snapshot=(snapshots or {}).get(t.name))
@@ -579,7 +544,7 @@ class Multiplexer:
             positioned=positioned,
         )
         self._slots.append(slot)
-        self._live.append(slot)
+        self._live.append(_Unit(slot))
         self.agg.n_tenants = len(self._slots)
         if tok is not None:
             tel.tracer.end(
@@ -636,7 +601,7 @@ class Multiplexer:
                 "queries_issued": stats.queries_issued,
                 "labels_applied": stats.labels_applied,
                 "draining": slot.draining,
-                "fused": slot.unit is not None,
+                "fused": len(slot.unit.slots) > 1,
             })
         return out
 
@@ -688,18 +653,13 @@ class Multiplexer:
         slot = self._slot(name)
         if slot.result is not None:
             raise ValueError(f"tenant {name!r} already finished; nothing to migrate")
-        if slot.unit is not None:
-            # Migrating out of a fused cohort: detach first (writes the
-            # member's stacked rows + pending plan back into its session),
-            # then the ordinary solo quiesce/snapshot flow applies.
-            unit = slot.unit
-            freed = unit.release(slot)
-            if not unit.slots and unit in self._live:
-                idx = self._live.index(unit)
-                self._live[idx : idx + 1] = freed
-            else:
-                self._live.extend(freed)
-            self._cohorts = {k: u for k, u in self._cohorts.items() if u.slots}
+        # Leave the unit first (a fused member's stacked rows and pending
+        # plan go back into its session), then the ordinary quiesce/snapshot
+        # flow applies.
+        unit = slot.unit
+        unit.release(slot)
+        if not unit.slots:
+            self._live.remove(unit)
         snapshot_capable = (
             getattr(slot.session.teacher, "snapshot_state", None) is not None
         )
@@ -712,8 +672,6 @@ class Multiplexer:
             slot.manager.wait()
             slot.manager.save(slot.session.t, tree)
         self._slots.remove(slot)
-        if slot in self._live:
-            self._live.remove(slot)
         self.agg.n_tenants = len(self._slots)
         if tok is not None:
             tel.tracer.end(tok, tenant=name, t=slot.session.t)
@@ -738,75 +696,38 @@ class Multiplexer:
             raise
 
     def _form_cohorts(self) -> None:
-        """Pack fusable live slots into cohorts by ``(cfg, mode, donate, S)``.
+        """Pack live units by ``(cfg, mode, donate, S)`` into one unit per
+        key.
 
         Runs at every round start, so tenants admitted mid-run (including
         live-migration snapshots restored with pending tickets) join a
-        matching cohort at the next scheduling boundary.  Singleton groups
-        stay on the solo slot path — a cohort only pays off with >= 2
-        members.  The stream width S is part of the key: cohort members
-        tick in lockstep, and fusing different widths would break the DRR
+        matching cohort at the next scheduling boundary.  Only units of
+        one join, and a key has at most one unit of several (the host).
+        The stream width S is part of the key: cohort members tick in
+        lockstep, and fusing different widths would break the DRR
         scheduler's per-tenant fairness (each fused member must cost
-        exactly what its solo slot would)."""
+        exactly what it would in a unit of its own)."""
         groups: dict = {}
         for u in self._live:
-            if not isinstance(u, _Slot) or u.unit is not None or u.draining:
+            key = u.fuse_key()
+            if key is not None:
+                groups.setdefault(key, []).append(u)
+        for units in groups.values():
+            if len(units) < 2:
                 continue
-            sess = u.session
-            if sess.started() and sess._p is None:
-                continue  # restored after its stream ended: drain only
-            key = (sess.cfg, sess.mode, sess._donate, u.s)
-            groups.setdefault(key, []).append(u)
-        for key, slots in groups.items():
-            unit = self._cohorts.get(key)
-            if unit is not None and unit.slots:
-                for s in slots:
-                    unit.attach(s)
-                    self._live.remove(s)
-            elif len(slots) >= 2:
-                unit = _CohortUnit(slots)
-                for s in slots:
-                    s.unit = unit
-                idx = min(self._live.index(s) for s in slots)
-                for s in slots:
-                    self._live.remove(s)
-                self._live.insert(idx, unit)
-                self._cohorts[key] = unit
-                tel = _telemetry.TELEMETRY
-                if tel is not None:
-                    tel.tracer.event(
-                        "cohort.pack", members=len(slots), s=unit.s,
-                        tenants=",".join(s.tenant.name for s in slots),
-                    )
-                    tel.registry.count("odl_mux_cohorts_packed")
-
-    def _step_unit(self, u, n_ticks: int) -> list:
-        """Step one scheduler unit; returns the units live after it (the
-        unit itself, plus any slots a cohort released this round — an
-        exhausted member immediately gets its first solo drain slice, like
-        the solo path's same-call drain)."""
-        out = []
-        if isinstance(u, _CohortUnit):
-            live, released = u.step(self.drain, n_ticks)
-            if live:
-                out.append(u)
-            else:
-                self._cohorts = {
-                    k: un for k, un in self._cohorts.items() if un is not u
-                }
-                tel = _telemetry.TELEMETRY
-                if tel is not None:
-                    tel.tracer.event("cohort.dissolve", released=len(released))
-                    tel.registry.count("odl_mux_cohorts_dissolved")
-            for r in released:
-                r.deficit = 0.0
-                if r.draining and not self.drain:
-                    r._finish()  # drain=False: settle, exactly like solo
-                elif not r.draining or r.step(self.drain, 0):
-                    out.append(r)
-        elif u.step(self.drain, n_ticks):
-            out.append(u)
-        return out
+            host = max(units, key=lambda u: len(u.slots))
+            packed = len(host.slots) == 1
+            joining = [u for u in units if u is not host]
+            host.attach([u.slots[0] for u in joining])  # all units of one
+            for u in joining:
+                self._live.remove(u)
+            tel = _telemetry.TELEMETRY
+            if packed and tel is not None:
+                tel.tracer.event(
+                    "cohort.pack", members=len(host.slots), s=host.s,
+                    tenants=",".join(s.tenant.name for s in host.slots),
+                )
+                tel.registry.count("odl_mux_cohorts_packed")
 
     def _round(self) -> bool:
         if self._t0 is None:
@@ -836,16 +757,16 @@ class Multiplexer:
             # that is only draining costs no device time and must not gate
             # everyone else's budget (a small drained tenant stuck waiting
             # out a slow teacher would otherwise collapse live tenants to
-            # ~1 tick per S_big/S_small rounds).  A cohort unit's cost is
-            # its (shared) member width, so each fused member sees the
-            # same credit/debit schedule as its solo slot.
+            # ~1 tick per S_big/S_small rounds).  A unit's cost is its
+            # (shared) member width, so each fused member sees the same
+            # credit/debit schedule as in a unit of its own.
             ticking = [u.s for u in self._live if not u.draining]
             credit = self.quantum * min(ticking) if ticking else 0
             nxt = []
             for u in self._live:
                 u.deficit += credit
                 n = int(u.deficit // u.s)
-                stepped = self._step_unit(u, n)
+                stepped = u.step(self.drain, n)
                 u.deficit -= u.last_ticks * u.s
                 if u.draining:
                     u.deficit = 0.0  # drained slices don't consume credit
@@ -854,7 +775,7 @@ class Multiplexer:
         else:
             nxt = []
             for u in self._live:
-                nxt.extend(self._step_unit(u, self.quantum))
+                nxt.extend(u.step(self.drain, self.quantum))
             self._live = nxt
         if tel is not None:
             # Scheduler-level meters: rounds, and the stream-step debits
@@ -914,11 +835,11 @@ def run(
     wall-clock interleaving changes.
 
     ``fuse`` (default True) packs tenants with the same ``(cfg, mode,
-    donate)`` and stream width into *cohorts* advanced by one fused
-    stacked dispatch per tick instead of one per tenant
-    (``engine/cohort.py``) — per-tenant results stay bit-for-bit identical
-    to the unfused (and solo) run; only device dispatch count and
-    wall-clock interleaving change.
+    donate)`` and stream width into *cohorts* advanced by one stacked
+    dispatch per tick instead of one per tenant (``engine/cohort.py``);
+    ``fuse=False`` ticks every tenant in a unit of its own.  Per-tenant
+    results are bit-for-bit identical either way (and to a solo run); only
+    device dispatch count and wall-clock interleaving change.
 
     ``snapshot_dir`` + ``snapshot_every`` enable per-tenant durability;
     ``resume=True`` restores tenants from their latest published snapshot

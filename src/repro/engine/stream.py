@@ -41,7 +41,9 @@ Pieces:
   explicit state machine: ``start(x0)`` dispatches the first plan,
   ``advance(next_tick)`` finishes the current tick (ask → poll → learn,
   fused with the next tick's plan), ``finish()`` drains and returns
-  ``(state, outputs, stats)``.  ``run`` drives a single session;
+  ``(state, outputs, stats)``.  The tick's device work runs in the one
+  tick engine, ``engine.cohort.CohortSession``, where a session on its
+  own is a group of one.  ``run`` drives a single session;
   ``engine.multiplex`` interleaves many sessions — with per-tenant
   configs, teachers, rings, and backpressure — over one process, sharing
   the bounded compiled-runner LRUs below.
@@ -271,14 +273,14 @@ class PendingTicket(NamedTuple):
 class PlanSlice:
     """Lazy row-window view of a cohort's full-width ``fleet.PlanOutput``.
 
-    Cohort fusion (``engine/cohort.py``) plans all members of a cohort in
-    one stacked dispatch; each member session's current plan and ring
-    entries then hold a ``PlanSlice`` instead of a solo-width
-    ``PlanOutput``.  Attribute access slices the full plan lazily
-    (device-side), and ``_asdict`` matches the NamedTuple protocol, so the
-    solo drain, snapshot (``snapshot._plan_to_tree``), and patch-learn
-    paths treat it exactly like a ``PlanOutput``.  ``materialize()`` turns
-    it into a real solo-width ``PlanOutput`` (detaching from the cohort).
+    A group of several (``engine/cohort.py``) plans all its members in one
+    stacked dispatch; each member session's current plan and ring entries
+    then hold a ``PlanSlice`` instead of a member-width ``PlanOutput``.
+    Attribute access slices the full plan lazily (device-side), and
+    ``_asdict`` matches the NamedTuple protocol, so a group of one's learn,
+    snapshot (``snapshot._plan_to_tree``), and patch-learn paths treat it
+    exactly like a ``PlanOutput``.  ``materialize()`` turns it into a real
+    member-width ``PlanOutput`` (detaching from the cohort).
     """
 
     __slots__ = ("full", "lo", "hi")
@@ -679,6 +681,21 @@ def _default_ship():
     return (lambda a: a) if jax.default_backend() == "cpu" else jax.device_put
 
 
+class _NoTracer:
+    """Records nothing: a tick's phase spans go here when telemetry is off."""
+
+    @staticmethod
+    def begin(name: str) -> None:
+        return None
+
+    @staticmethod
+    def end(token, **labels) -> None:
+        return None
+
+
+NO_TRACER = _NoTracer()
+
+
 class StreamSession:
     """One stream's (one tenant's) async-teacher runtime as a state machine.
 
@@ -691,12 +708,14 @@ class StreamSession:
         sess.advance(None)      # finish the last tick (no next plan)
         state, outs, stats = sess.finish()   # drain + accounting + outputs
 
-    ``run`` drives exactly this sequence for a single session;
-    ``engine.multiplex.run`` interleaves many sessions round-robin so N
-    tenants share one process (and, via the bounded runner LRUs, one
-    compiled executable per distinct ``(cfg, mode, donate)``).  Because the
-    per-tenant op sequence is identical either way, a multiplexed tenant
-    reproduces its solo ``run`` bit-for-bit.
+    The session holds the per-tenant half: its ring, teacher, backpressure
+    policy, stats, output collection and tick cursor.  The device work of a
+    tick runs in the tick engine, ``engine.cohort.CohortSession``:
+    ``start`` / ``advance`` / ``finish`` drive the session's own group of
+    one, and the multiplexer (``engine.multiplex``) may instead tick it in
+    a group of several same-shaped tenants.  The per-tenant op sequence is
+    the same either way, so a multiplexed tenant reproduces its solo
+    ``run`` bit-for-bit.
 
     ``backpressure`` picks the ring-saturation policy (see module
     docstring / ``BACKPRESSURE_POLICIES``).
@@ -743,16 +762,7 @@ class StreamSession:
         # (never query/learn) and are excluded from ``stream_steps``.
         self.width = int(jax.tree.leaves(state)[0].shape[0])
         self.live = None if live is not None and live >= self.width else live
-        self._avail = None  # device (S,) bool, built lazily at start()
-        if self.live is None:
-            self._plan_fn = _plan_runner(cfg, mode, donate)
-            self._fused_fn = _learn_plan_runner(cfg, mode, donate)
-        else:
-            plan_raw = _plan_avail_runner(cfg, mode, donate)
-            self._plan_fn = lambda *a: plan_raw(*a, self._avail)
-            self._fused_fn = _learn_plan_avail_runner(cfg, mode, donate)
-        self._learn_fn = _learn_runner(cfg, donate)
-        self._laddered: set = set()  # (runner, width) compiled by learn_call
+        self._cohort = None  # the group this session ticks in (engine/cohort.py)
         # ``block``: asks waiting for a ring slot (bounded like the ring;
         # overflow drops the oldest deferred ask, metered).
         self._deferred: "collections.deque[DeferredAsk]" = collections.deque()
@@ -762,7 +772,6 @@ class StreamSession:
                       "mode_training")
         }
         self._trained_rows: list[np.ndarray] = []
-        self._full_mask_dev = None  # cached device-side all-True apply mask
         self._x = None  # current tick's features (plan dispatched, not asked)
         self._p = None  # current tick's PlanOutput
         self.t = 0
@@ -779,23 +788,22 @@ class StreamSession:
     def started(self) -> bool:
         return self._t_start is not None
 
-    # odlint: shard-local
+    def group(self):
+        """The session's own group of one (``engine.cohort``), made on
+        first use; raises while the session ticks in a group of several."""
+        from repro.engine import cohort
+
+        if self._cohort is None:
+            self._cohort = cohort.CohortSession([self])
+        elif len(self._cohort.members) > 1:
+            raise RuntimeError("a fused member ticks and drains with its cohort")
+        return self._cohort
+
     def start(self, x0) -> None:
         """Dispatch the first tick's plan (nothing pending yet)."""
         assert not self.started(), "session already started"
-        self._t_start = time.perf_counter()
-        if self.live is not None and self._avail is None:
-            self._avail = jnp.arange(int(np.shape(x0)[0])) < self.live
-        x0 = self.ship(x0)
-        (new_prune, new_drift, new_meter), p = self._plan_fn(
-            self.state.elm, self.state.prune, self.state.drift, self.state.meter, x0
-        )
-        self.state = self.state._replace(
-            prune=new_prune, drift=new_drift, meter=new_meter
-        )
-        self._x, self._p = x0, p
+        self.group().tick([x0])
 
-    # odlint: shard-local
     def advance(self, nxt) -> None:
         """Finish the current tick (ask → poll → learn) and plan ``nxt``.
 
@@ -803,114 +811,8 @@ class StreamSession:
         iterator is exhausted — the learn of any same-tick replies then runs
         unfused.  Mirrors one iteration of the double-buffered ``run`` loop.
         """
-        tel = _telemetry.TELEMETRY
-        if tel is None:
-            self._advance(nxt, None, None)
-            return
-        labels = {"t": self.t, **self.telemetry_labels}
-        tok = tel.tracer.begin("stream.tick")
-        try:
-            self._advance(nxt, tel, labels)
-        finally:
-            tel.tracer.end(tok, **labels)
-
-    def _advance(self, nxt, tel, labels: Optional[dict]) -> None:
-        """``advance``'s body; with telemetry on (``tel``) it records the
-        tick's phases as ``tick.*`` spans and fills the tick span's
-        ``labels``."""
-        x, p = self._x, self._p
-        assert p is not None, "advance() before start()"
-        t = self.t
-        t0 = time.perf_counter()
-        if nxt is not None:
-            tok = tel.tracer.begin("tick.ship") if tel is not None else None
-            nxt = self.ship(nxt)
-            if tok is not None:
-                tel.tracer.end(tok)
-        tok = tel.tracer.begin("tick.sync") if tel is not None else None
-        queried_host = np.asarray(p.queried)  # host syncs on tick t here
-        if tok is not None:
-            tel.tracer.end(tok, what="mask")
-        if self.collect:
-            tok = tel.tracer.begin("tick.sync") if tel is not None else None
-            for k in self._cols:
-                self._cols[k].append(np.asarray(getattr(p, k)))
-            if tok is not None:
-                tel.tracer.end(tok, what="cols")
-            self._trained_rows.append(np.zeros(queried_host.shape, bool))
-        n_q = int(queried_host.sum())
-        if n_q:
-            # Decision-time metering: the comm meter already charged these
-            # queries inside plan; every one of them must end in exactly one
-            # of applied / dropped / lost / coalesced.
-            self.stats.queries_issued += n_q
-            tok = tel.tracer.begin("tick.ask") if tel is not None else None
-            self._submit(x, queried_host, p, t)
-            if tok is not None:
-                tel.tracer.end(tok, queries=n_q)
-        tok = tel.tracer.begin("tick.poll") if tel is not None else None
-        applies = [
-            a
-            for a in (self._claim(r, t) for r in self.teacher.poll(t))
-            if a is not None
-        ]
-        # Replies just freed ring slots: submit deferred (``block``) asks.
-        self._flush_deferred(t)
-        if tok is not None:
-            tel.tracer.end(tok, replies=len(applies))
-        if nxt is not None:
-            # Steady state: fuse the last reply's learn with the next tick's
-            # plan into one dispatch (earlier replies, if any, apply first,
-            # so all of tick t's answers land before tick t+1 is planned).
-            if applies:
-                for args, rows in applies[:-1]:
-                    self._learn(args, rows, tel)
-                args, rows = applies[-1]
-                tail = (nxt,) if self.live is None else (nxt, self._avail)
-                tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-                (elm2, prune2, drift2, meter2), p_next = self._learn_call(
-                    self._fused_fn,
-                    (self.state.elm, self.state.prune, self.state.drift,
-                     self.state.meter, *args, *tail),
-                    rows,
-                )
-                if tok is not None:
-                    tel.tracer.end(tok, fn="learn_plan",
-                                   rows=self.width if rows is None else len(rows))
-                self.state = EngineState(
-                    elm=elm2, prune=prune2, drift=drift2, meter=meter2
-                )
-            else:
-                tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-                (new_prune, new_drift, new_meter), p_next = self._plan_fn(
-                    self.state.elm, self.state.prune, self.state.drift,
-                    self.state.meter, nxt
-                )
-                if tok is not None:
-                    tel.tracer.end(tok, fn="plan")
-                self.state = self.state._replace(
-                    prune=new_prune, drift=new_drift, meter=new_meter
-                )
-        else:
-            for args, rows in applies:
-                self._learn(args, rows, tel)
-            p_next = None
-        self.stats.ticks += 1
-        self.stats.stream_steps += (
-            self.live if self.live is not None else int(np.shape(x)[0])
-        )
-        tick_s = time.perf_counter() - t0
-        self.stats.tick_ms.append(tick_s * 1e3)
-        if tick_s > 0:
-            rate = 1.0 / tick_s
-            ema = self.stats.tick_rate_ema
-            self.stats.tick_rate_ema = (
-                rate if ema == 0.0 else ema + TICK_RATE_EMA_ALPHA * (rate - ema)
-            )
-        self.t += 1
-        self._x, self._p = nxt, p_next
-        if labels is not None:
-            labels.update(queries=n_q, learns=len(applies))
+        assert self._p is not None, "advance() before start()"
+        self.group().tick([nxt])
 
     def drain_replies(
         self,
@@ -936,7 +838,7 @@ class StreamSession:
             if drained >= max_ticks:
                 return True
             replies = self._poll_and_apply()
-            self._flush_deferred(self.t)
+            self._flush_deferred()
             self.t += 1
             drained += 1
             if self.teacher.in_flight() == 0 and not replies:
@@ -995,14 +897,14 @@ class StreamSession:
             )
 
     def _poll_and_apply(self) -> list[TeacherReply]:
+        """A drain's poll: claim what arrived and learn it through the
+        session's group of one."""
         replies = self.teacher.poll(self.t)
-        for reply in replies:
-            claimed = self._claim(reply, self.t)
-            if claimed is not None:
-                self._learn(*claimed)
+        claims = self._claims(replies)
+        if claims:
+            self.group().learn_replies(claims)
         return replies
 
-    # odlint: shard-local
     def finish(
         self, drain: bool = True
     ) -> tuple[EngineState, Optional[FleetStepOutput], StreamStats]:
@@ -1137,8 +1039,7 @@ class StreamSession:
             return
         self._ask(x, queried, p, t)
 
-    def _flush_deferred(self, now: int) -> None:
-        del now
+    def _flush_deferred(self) -> None:
         while self._deferred and not self.ring.full():
             d = self._deferred.popleft()
             # Ask with the ORIGIN tick — the tick the query is about — so
@@ -1147,14 +1048,12 @@ class StreamSession:
             # teacher (array_labels) looks up the right tick's labels.
             self._ask(d.x, d.queried, d.plan, d.tick)
 
-    def _claim_entry(self, reply: TeacherReply, now: int):
-        """Accounting half of a reply claim: resolve the ticket against the
-        ring with all drop/orphan/loss metering and trained-row marking.
-        Returns ``(entry, mask)`` — the ring entry and the host-side apply
-        mask — or None when nothing is applicable.  ``_claim`` composes
-        this with ``_build_learn_args``; the cohort engine
-        (``engine/cohort.py``) uses the halves separately so it can scatter
-        many members' masks into one full-width fused learn."""
+    def _claim(self, reply: TeacherReply):
+        """Resolve a reply against the ring with all drop/orphan/loss
+        metering and trained-row marking.  Returns ``(entry, mask, reply)``
+        — the ring entry and the host-side apply mask — or None when
+        nothing is applicable; the group (``engine/cohort.py``) scatters
+        the mask into its learn."""
         stats = self.stats
         ent = self.ring.pop(reply.ticket)
         if ent is None:
@@ -1175,67 +1074,58 @@ class StreamSession:
         # Partial answer: the unanswered residue of this ticket will never
         # get labels — meter it now, at the only moment it is knowable.
         stats.queries_lost += asked - n
-        stats.label_latency_ticks.append(now - ent.tick)
+        stats.label_latency_ticks.append(self.t - ent.tick)
         if self.collect and ent.tick < len(self._trained_rows):
             self._trained_rows[ent.tick] |= mask
-        return ent, mask
+        return ent, mask, reply
 
-    def _build_learn_args(self, ent: PendingTicket, reply: TeacherReply,
-                          mask: np.ndarray):
-        """Device half of a reply claim: package one claimed reply as
-        ``_learn_fn`` args (plan-time context + shipped labels + mask)."""
-        n = int(mask.sum())
-        if n == mask.shape[0]:
-            # Steady state (everyone queried, everyone answered): reuse one
-            # device-resident mask instead of a fresh upload per tick.
-            if self._full_mask_dev is None or self._full_mask_dev.shape != mask.shape:
-                self._full_mask_dev = jnp.ones(mask.shape, jnp.bool_)
-            mask_dev = self._full_mask_dev
-        else:
-            mask_dev = jnp.asarray(mask)
-        p = ent.plan
-        return (
-            p.h,
-            self.ship(np.asarray(reply.labels, np.int32)),
-            p.pred,
-            p.confidence,
-            mask_dev,
-            p.controller_on,
-            p.theta,
-        )
+    def _claims(self, replies: list[TeacherReply]) -> list:
+        return [c for c in map(self._claim, replies) if c is not None]
 
-    def _claim(self, reply: TeacherReply, now: int):
-        """Resolve a reply against the ring; returns ``(learn args, rows)``
-        — ``rows`` its ``learn_rows_index``, metered in the stats — or None,
-        with all drop/orphan/loss accounting applied."""
-        claimed = self._claim_entry(reply, now)
-        if claimed is None:
-            return None
-        ent, mask = claimed
-        rows = learn_rows_index(mask)
-        self.stats.count_learn(
-            self.width if rows is None else len(rows), rows is not None
-        )
-        return self._build_learn_args(ent, reply, mask), rows
+    # -- the member's half of a tick (driven by engine/cohort.py) ----------
 
-    def _learn_call(self, fn, args: tuple, rows: Optional[np.ndarray]):
-        return learn_call(fn, args, rows, self.width, self._laddered, self.ship)
+    def _intake(self, queried: np.ndarray, cols: Optional[dict], tr) -> int:
+        """Take in the current tick: collect its host columns (``cols``),
+        open its trained row, and route its decided queries through the
+        backpressure policy.  Returns the queries decided."""
+        if self.collect:
+            for k, col in self._cols.items():
+                col.append(cols[k])
+            self._trained_rows.append(np.zeros(queried.shape, bool))
+        n_q = int(queried.sum())
+        if n_q:
+            # Decision-time metering: the comm meter already charged these
+            # queries inside plan; every one of them must end in exactly one
+            # of applied / dropped / lost / coalesced.
+            self.stats.queries_issued += n_q
+            tok = tr.begin("tick.ask")
+            self._submit(self._x, queried, self._p, self.t)
+            tr.end(tok, queries=n_q)
+        return n_q
 
-    def _learn(self, args, rows: Optional[np.ndarray], tel=None) -> None:
-        """One unfused learn dispatch over ``rows`` (``_claim``); inside a
-        tick its caller passes the enabled telemetry (``tel``) to record it
-        as ``tick.dispatch``."""
-        tok = tel.tracer.begin("tick.dispatch") if tel is not None else None
-        new_elm, new_prune = self._learn_call(
-            self._learn_fn,
-            (self.state.elm, self.state.prune, self.state.drift, self.state.meter,
-             *args),
-            rows,
-        )
-        if tok is not None:
-            tel.tracer.end(tok, fn="learn",
-                           rows=self.width if rows is None else len(rows))
-        self.state = self.state._replace(elm=new_elm, prune=new_prune)
+    def _poll(self, tr) -> list:
+        """Claim the replies that arrived by this tick (``_claim``'s
+        triples, in arrival order), then submit deferred asks into the
+        slots they freed."""
+        tok = tr.begin("tick.poll")
+        claims = self._claims(self.teacher.poll(self.t))
+        self._flush_deferred()
+        tr.end(tok, replies=len(claims))
+        return claims
+
+    def _end_tick(self, tick_s: float) -> None:
+        """Account one finished tick of ``tick_s`` wall seconds."""
+        st = self.stats
+        st.ticks += 1
+        st.stream_steps += self.width if self.live is None else self.live
+        st.tick_ms.append(tick_s * 1e3)
+        if tick_s > 0:
+            rate = 1.0 / tick_s
+            ema = st.tick_rate_ema
+            st.tick_rate_ema = (
+                rate if ema == 0.0 else ema + TICK_RATE_EMA_ALPHA * (rate - ema)
+            )
+        self.t += 1
 
 
 def run(
@@ -1300,7 +1190,8 @@ def run(
 
 class ShardedStreamSession:
     """N shard-local ``StreamSession``s advanced in lockstep over row
-    windows of one full-width tick source.
+    windows of one full-width tick source; each ticks as a group of one of
+    the tick engine (``engine/cohort.py``), the shards in turn.
 
     Everything per-tick is shard-local: shard k's ``EngineState`` rows live
     on device k (the active mesh's devices, or wherever ``devices`` says);

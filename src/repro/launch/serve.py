@@ -39,13 +39,13 @@ devices first: ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 round robin in stream-step units, so a huge tenant cannot starve small
 ones.
 
-``--fuse-cohorts`` (default on) stacks same-shaped tenants — same engine
-config, mode, and stream width — into one cohort whose states ride a
-single batched plan/learn dispatch per quantum (``repro.engine.cohort``),
-instead of one dispatch per tenant.  Everything tenant-visible (pending
-rings, teachers, backpressure, accounting, snapshots, migration) stays
-per-tenant and bit-for-bit identical to the unfused path; ``off`` keeps
-one dispatch per tenant.
+``--fuse-cohorts`` (default on) decides how tenants group in the one tick
+engine (``repro.engine.cohort``): on, same-shaped tenants — same engine
+config, mode, and stream width — tick as one cohort whose states ride a
+single batched plan/learn dispatch per tick; off, every tenant ticks in a
+group of its own, one dispatch per tenant.  Everything tenant-visible
+(pending rings, teachers, backpressure, accounting, snapshots, migration)
+stays per-tenant and bit-for-bit identical either way.
 
 Durable sessions (``repro.engine.snapshot``): ``--snapshot-dir`` +
 ``--snapshot-every`` publish per-tenant session snapshots atomically
@@ -722,9 +722,9 @@ def main(argv=None):
                     help="rr: fixed quantum-tick round robin; drr: deficit "
                     "round robin in stream-step units (size-fair)")
     ap.add_argument("--fuse-cohorts", default="on", choices=("on", "off"),
-                    help="stack same-shaped tenants into one batched "
-                    "plan/learn dispatch per quantum (bit-for-bit identical "
-                    "to unfused; off: one dispatch per tenant)")
+                    help="on: same-shaped tenants tick as one cohort, one "
+                    "batched plan/learn dispatch per tick; off: every tenant "
+                    "ticks in a group of its own (bit-for-bit identical)")
     ap.add_argument("--teacher", default="latency", choices=("latency", "rpc"),
                     help="latency: in-process tick-granular model; "
                     "rpc: loopback TCP label server with timeout->loss")

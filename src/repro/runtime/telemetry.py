@@ -13,7 +13,7 @@ This module gives them one shared, scrape-able surface:
   scheduler rounds, tick-source pulls, whole ticks and the phases inside
   them (host syncs, teacher ask and poll, next-tick ship, device
   dispatch), ring evictions, snapshot save/restore, RPC flush/reconnect,
-  cohort pack/dissolve, and migration extract→ship→admit — exported as
+  cohort packing, and migration extract→ship→admit — exported as
   Chrome ``trace_event`` JSON (open it in ``chrome://tracing`` /
   Perfetto).  ``SPAN_NAMES`` catalogs every name the program records.
 
@@ -79,11 +79,11 @@ __all__ = [
 SPAN_NAMES = (
     # scheduler (engine/multiplex.py)
     "mux.round", "mux.source",
-    # one tick of a solo session or a fused cohort, and its phases
+    # one tick of a group of one or of a fused cohort, and its phases
     "stream.tick", "cohort.tick",
     "tick.sync", "tick.ask", "tick.poll", "tick.ship", "tick.dispatch",
     # events and spans off the tick path
-    "ring.evict", "cohort.pack", "cohort.dissolve",
+    "ring.evict", "cohort.pack",
     "rpc.flush", "rpc.reconnect",
     "snapshot.save", "snapshot.restore",
     "migrate.extract", "migrate.ship", "migrate.admit",

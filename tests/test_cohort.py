@@ -86,6 +86,12 @@ def _solo(cfg, xs, teacher, **kw):
     )
 
 
+def _fused(mux):
+    """The tenant names of each live unit that holds several tenants."""
+    return sorted(sorted(s.tenant.name for s in u.slots)
+                  for u in mux._live if len(u.slots) > 1)
+
+
 def _tenant(name, cfg, xs, teacher, **kw):
     return multiplex.Tenant(
         name=name, state=engine.init_fleet(cfg, xs.shape[1]),
@@ -104,11 +110,12 @@ def _tenant(name, cfg, xs, teacher, **kw):
 def test_fused_matches_unfused_and_solo_bit_for_bit(sched, quantum):
     """Four same-shaped tenants under faulty teachers, UNEQUAL stream
     lengths (members exhaust and detach mid-run, the cohort restacks and
-    finally dissolves): the fused multiplexer must reproduce both the
+    finally ticks its last member as a group of one): the fused
+    multiplexer must reproduce both the
     unfused multiplexer and four solo runs exactly — states, collected
     outputs, every deterministic counter, label-latency histogram."""
     cfg = _cfg()
-    lens = [30, 30, 22, 14]  # detach + dissolve paths, not just lockstep
+    lens = [30, 30, 22, 14]  # detach + shrink paths, not just lockstep
     datas = [_stream_data(cfg, t, 4, seed=70 + i) for i, t in enumerate(lens)]
 
     solo = [
@@ -130,7 +137,7 @@ def test_fused_matches_unfused_and_solo_bit_for_bit(sched, quantum):
     mux = multiplex.Multiplexer(tenants(), sched=sched, quantum=quantum,
                                 fuse=True)
     assert mux.round()  # one scheduling round forms the cohort...
-    assert any(u.slots for u in mux._cohorts.values()), (
+    assert _fused(mux) == [[f"tenant{i}" for i in range(4)]], (
         "4 same-shaped tenants must actually fuse (else this test "
         "proves nothing)"
     )
@@ -173,7 +180,7 @@ def test_fused_cohort_wide_enough_to_compact_matches_solo_bit_for_bit():
          for i, (xs, ys) in enumerate(datas)],
         quantum=2, fuse=True)
     assert mux.round()
-    assert any(u.slots for u in mux._cohorts.values())
+    assert _fused(mux) == [["t0", "t1"]]
     fused, _ = mux.run()
 
     for i, (st, outs, stats) in enumerate(solo):
@@ -214,12 +221,8 @@ def test_mixed_shapes_pack_into_separate_cohorts():
         for name, c, s in specs
     ], fuse=True)
     assert mux.round()
-    live_cohorts = [u for u in mux._cohorts.values() if u.slots]
-    assert len(live_cohorts) == 2
-    fused_names = sorted(
-        s.tenant.name for u in live_cohorts for s in u.slots
-    )
-    assert fused_names == ["a0", "a1", "b0", "b1"]  # "w" fused nowhere
+    # "w" fused nowhere
+    assert _fused(mux) == [["a0", "a1"], ["b0", "b1"]]
     results, _ = mux.run()
 
     for name, c, s in specs:
@@ -269,7 +272,7 @@ def test_migrate_out_of_fused_cohort_and_restore_into_cohort_slot():
     while mux.round():
         if mux.session("t0").t >= 13:
             break
-    assert mux._slot("t0").unit is not None, "t0 must be fused when extracted"
+    assert len(mux._slot("t0").unit.slots) > 1, "t0 must be fused when extracted"
     tree, rest = mux.extract("t0", quiesce_ticks=0)
     results_a, _ = mux.run()  # t1/t2 keep going (re-fused as a pair)
 
@@ -289,7 +292,7 @@ def test_migrate_out_of_fused_cohort_and_restore_into_cohort_slot():
         positioned=True,  # rest is extract()'s live iterator
     )
     assert mux_b.round()
-    assert mux_b._slot("t0").unit is not None, (
+    assert _fused(mux_b) == [["d", "t0"]], (
         "the restored tenant must land in a cohort slot"
     )
     results_b, _ = mux_b.run()
@@ -328,7 +331,7 @@ def test_admit_into_running_fused_mux_joins_cohort_and_matches_solo():
     mux.admit(_tenant("t2", cfg, datas[2][0],
                       _lossy_teacher(datas[2][1], seed=52)))
     assert mux.round()
-    assert mux._slot("t2").unit is not None, "late tenant must join the cohort"
+    assert _fused(mux) == [["t0", "t1", "t2"]], "late tenant must join the cohort"
     results, _ = mux.run()
 
     for i, (st, outs, stats) in enumerate(solo):

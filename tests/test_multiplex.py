@@ -591,7 +591,7 @@ def test_drr_is_bit_for_bit_and_does_not_let_big_tenants_starve_small():
     big_while_small_live = []
     while mux.round():
         if mux._slot("small").result is None:
-            big_while_small_live.append(mux._slot("big").last_ticks)
+            big_while_small_live.append(mux._slot("big").unit.last_ticks)
     res_drr, agg_drr = mux.results()
 
     for name in ("small", "big"):

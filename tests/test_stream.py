@@ -219,6 +219,63 @@ def test_padded_session_compacts_through_the_avail_runner():
     assert not out_p.queried[:, live:].any()
 
 
+def test_group_of_one_keeps_the_solo_dispatch_pattern(monkeypatch):
+    """A session ticking on its own (a group of one of the tick engine)
+    makes the dispatches a solo tenant always made: on a tick with replies
+    every reply but the last is one ``run_learn`` and the last is fused
+    with the next plan in one ``run_learn_plan``; a tick without replies is
+    one ``run_plan``.  Its plan is the plain ``PlanOutput``, and device
+    features it is handed stay device arrays in its ring."""
+    calls = []
+
+    class Counted:
+        def __init__(self, name, fn):
+            self.name, self.fn = name, fn
+
+        def __call__(self, *args, **kw):
+            calls.append(self.name)
+            return self.fn(*args, **kw)
+
+        def lower(self, *args, **kw):
+            return self.fn.lower(*args, **kw)
+
+    for name in ("plan", "learn", "learn_plan"):
+        factory = getattr(stream, f"_{name}_runner")
+        monkeypatch.setattr(stream, f"_{name}_runner",
+                            lambda *key, f=factory, n=name: Counted(n, f(*key)))
+
+    cfg = _cfg(n_in=561, n_hidden=128, n_out=6)  # the paper's HAR head
+    t_len, s_len = 48, 2 * stream.LEARN_ROWS_FLOOR
+    xs, ys = _stream_data(cfg, t_len, s_len, seed=8, calm=slice(128, None))
+    teacher = stream.LatencyTeacher(stream.array_labels(ys), latency=2, jitter=2, seed=3)
+    sess = stream.StreamSession(engine.init_fleet(cfg, s_len), cfg, teacher,
+                                mode="train_phase", capacity=8)
+
+    def learns():
+        return sess.stats.learns_compact + sess.stats.learns_full
+
+    sess.start(xs[0])
+    assert calls == ["plan"]
+    seen = {"plan": 0, "one": 0, "several": 0}
+    for t in range(1, t_len + 1):
+        calls.clear()
+        before = learns()
+        sess.advance(xs[t] if t < t_len else None)
+        n = learns() - before
+        if t == t_len:
+            assert calls == ["learn"] * n, calls  # the last tick plans nothing
+            break
+        assert calls == (["learn"] * (n - 1) + ["learn_plan"] if n else ["plan"]), (t, calls)
+        seen["plan" if n == 0 else "one" if n == 1 else "several"] += 1
+        assert type(sess._p) is engine.fleet.PlanOutput
+        assert isinstance(sess._x, jax.Array)
+        assert all(isinstance(e.x, jax.Array) for e in sess.ring.entries())
+    assert all(seen.values()), seen
+    assert sess.stats.learns_compact > 0
+    st, _, stats = sess.finish()
+    assert stats.reconciled, stats.summary()
+
+
 def test_ring_overflow_drops_oldest_and_meters_it():
     """With capacity 2 and a teacher slower than the stream, only the two
     youngest tickets survive; evictions and orphaned replies are counted."""
